@@ -23,10 +23,9 @@ the measured step wall time and the attributed share against the LOOP
 wall is a real <1 number — the BENCH_8 claim is that >= 90% of round
 wall time lands in the named buckets.
 
-Two evidence rows document the per-dispatch trace tooling itself:
-``jax.profiler`` traces (works on every backend) and the
-``XLA_FLAGS=--xla_hlo_profile`` per-HLO CPU fallback (SNIPPETS.md
-snippet 3) exercised in a subprocess.
+One evidence row documents the per-dispatch trace tooling itself:
+``jax.profiler`` traces, which work on every backend and are taken in
+this process (only the process that holds the chip can trace it).
 
 On top of the breakdown, the two optimizations it motivated are
 measured head-to-head and their claims recorded machine-checkably:
@@ -49,7 +48,6 @@ import collections
 import glob
 import json
 import os
-import subprocess
 import sys
 import tempfile
 import time
@@ -361,35 +359,9 @@ def _trace_rows(model, params, vocab: int) -> list:
                  f"{n_files} trace artifacts captured"
                  f" on {jax.default_backend()}"))
 
-    # per-HLO CPU fallback (SNIPPETS.md snippet 3): historically XLA
-    # logged an execution profile per computation to stderr under
-    # XLA_FLAGS=--xla_hlo_profile + TF_CPP_MIN_LOG_LEVEL=0. Exercised in
-    # a subprocess — the flag only takes effect at backend init, and we
-    # must not poison this process's XLA options. On current XLA builds
-    # the CPU runtime ACCEPTS the flag but no longer emits the per-HLO
-    # dump — the row records both facts; ``jax.profiler`` above is the
-    # per-dispatch trace path that works on every backend here.
-    code = ("import jax, jax.numpy as jnp;"
-            "f = jax.jit(lambda x: (x @ x).sum());"
-            "print(float(f(jnp.ones((64, 64)))))")
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_hlo_profile",
-               TF_CPP_MIN_LOG_LEVEL="0")
-    t0 = time.perf_counter()
-    proc = subprocess.run([sys.executable, "-c", code], env=env,
-                          capture_output=True, text=True, timeout=300)
-    sec = time.perf_counter() - t0
-    accepted = proc.returncode == 0
-    dumped = "execution profile" in proc.stderr.lower()
-    rows.append(("profiling/xla_hlo_profile_subprocess", sec * 1e6,
-                 f"flag accepted={accepted}; per-HLO stderr dump"
-                 f" emitted={dumped} on this XLA build"
-                 f" (jax.profiler is the per-dispatch path)"))
     LAST_CLAIMS["trace_tooling"] = {
         "jax_profiler_artifacts": n_files,
         "jax_profiler_trace_works": n_files > 0,
-        "xla_hlo_profile_flag_accepted": accepted,
-        "xla_hlo_profile_dump_emitted": dumped,
     }
     return rows
 

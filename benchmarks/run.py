@@ -70,6 +70,8 @@ def main(argv=None) -> None:
     ap.add_argument("--record", default=None, metavar="DIR",
                     help="write each module's BENCH_RECORD json here")
     args = ap.parse_args(argv)
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     mods = discover()
     if args.only:

@@ -1,9 +1,1 @@
-"""Paper reproduction package.
-
-Importing any ``repro.*`` module installs the JAX version shims
-(see ``repro.dist.compat``) so the repo's modern-jax call sites run on
-the pinned 0.4.x toolchain.
-"""
-from repro.dist import compat as _compat
-
-_compat.install()
+"""Paper reproduction package."""

@@ -1,4 +1,9 @@
-"""--arch registry: full production configs + reduced smoke variants."""
+"""--arch registry: full production configs + reduced smoke variants.
+
+Every published config ``<arch>`` also resolves as ``<arch>-smoke``: the
+same family, pattern and features at tiny widths, for CPU tests and
+quickstarts (``get("qwen2-7b-smoke")``).
+"""
 from __future__ import annotations
 
 import dataclasses
@@ -29,10 +34,17 @@ ARCHS: Dict[str, ModelConfig] = {
 ASSIGNED = [k for k in ARCHS if k != "distilbert-imdb"]
 
 
+SMOKE_SUFFIX = "-smoke"
+
+
 def get(name: str) -> ModelConfig:
-    if name not in ARCHS:
-        raise KeyError(f"unknown arch {name!r}; known: {sorted(ARCHS)}")
-    return ARCHS[name]
+    """A published config by name, or its smoke preset (``<arch>-smoke``)."""
+    base = name[:-len(SMOKE_SUFFIX)] if name.endswith(SMOKE_SUFFIX) else name
+    if base not in ARCHS:
+        known = sorted(ARCHS) + sorted(a + SMOKE_SUFFIX for a in ARCHS)
+        raise KeyError(f"unknown arch {name!r}; known: {known}")
+    cfg = ARCHS[base]
+    return reduce_for_smoke(cfg) if base != name else cfg
 
 
 def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
@@ -54,7 +66,7 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
                         n_groups=1, chunk=8)
     return dataclasses.replace(
         cfg,
-        name=cfg.name + "-smoke",
+        name=cfg.name + SMOKE_SUFFIX,
         n_layers=len(cfg.pattern) * 2,
         n_enc_layers=2 if cfg.encdec else 0,
         enc_d_model=64 if cfg.encdec else 0,
@@ -72,4 +84,8 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
 
 
 def smoke(name: str) -> ModelConfig:
-    return reduce_for_smoke(get(name))
+    """The smoke preset of published arch ``name``: ``get(name + "-smoke")``."""
+    if name.endswith(SMOKE_SUFFIX):
+        raise KeyError(f"{name!r} already names a smoke preset; use "
+                       f"get({name!r}) or smoke({name[:-len(SMOKE_SUFFIX)]!r})")
+    return get(name + SMOKE_SUFFIX)

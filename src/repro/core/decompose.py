@@ -34,13 +34,24 @@ def coverage_ok(chunks: List[Chunk], n_items: int) -> bool:
     return pos == n_items
 
 
+def _result_key(job: BatchJob, chunk_id: int) -> str:
+    return f"job/{job.job_id}/result/{chunk_id}"
+
+
+def commit_result(store: ArtifactStore, job: BatchJob, chunk_id: int,
+                  payload) -> bool:
+    """Idempotent per-chunk commit: the first writer wins. Returns
+    whether this call was that first writer."""
+    return store.put(_result_key(job, chunk_id), pickle.dumps(payload),
+                     overwrite=False)
+
+
 def merge(store: ArtifactStore, job: BatchJob,
           chunks: List[Chunk]) -> np.ndarray:
     """Reassemble committed per-chunk predictions in dataset order."""
     out = np.full(job.dataset.n_items, -1, np.int64)
     for c in chunks:
-        key = f"job/{job.job_id}/result/{c.chunk_id}"
-        payload = pickle.loads(store.get(key))
+        payload = pickle.loads(store.get(_result_key(job, c.chunk_id)))
         preds = np.asarray(payload["predictions"])
         assert len(preds) == c.n_items, (
             f"chunk {c.chunk_id}: {len(preds)} preds for {c.n_items} items")

@@ -7,7 +7,10 @@ and *chains* a re-invocation, which (cold- or warm-) starts, reloads
 state, and resumes — exactly the cycle in the paper's Fig. 1 (left).
 
 Fault tolerance: a crash loses only the work since the last per-batch
-cursor checkpoint; the chain restarts from the cursor.
+cursor checkpoint; the chain restarts from the cursor. Each finished
+batch commits its result to the store under the same first-writer-wins
+key the parallel orchestrator uses, so ``decompose.merge`` reads either
+run's predictions.
 """
 from __future__ import annotations
 
@@ -16,6 +19,7 @@ import json
 from typing import Callable, List, Optional
 
 from repro.core.cost_model import price_report
+from repro.core.decompose import commit_result
 from repro.core.faults import NO_FAULTS, FaultInjector
 from repro.core.job import BatchJob, Chunk, InvokeOutcome, JobReport, TaskRecord
 from repro.core.store import ArtifactStore
@@ -87,6 +91,8 @@ class MonolithicRunner:
                         {"t": round(clock, 3), "kind": "crash",
                          "cursor": cursor})
                     break
+                commit_result(self.store, job, chunk.chunk_id,
+                              outcome.payload)
                 cursor += 1
                 self.store.put(cursor_key,
                                json.dumps({"cursor": cursor}).encode())

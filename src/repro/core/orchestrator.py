@@ -26,8 +26,9 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from repro.core.cost_model import AWSPriceBook, price_report
+from repro.core.decompose import commit_result
 from repro.core.faults import NO_FAULTS, FaultInjector
-from repro.core.job import BatchJob, Chunk, InvokeOutcome, JobReport, TaskRecord
+from repro.core.job import BatchJob, Chunk, JobReport, TaskRecord
 from repro.core.store import ArtifactStore
 from repro.core.worker import ServerlessFunction
 
@@ -160,9 +161,8 @@ class Orchestrator:
                         self._log(clock, "chunk_failed", chunk=cid)
             else:
                 rec.billed_s = rec.duration_s
-                first = self.store.put(
-                    f"job/{job.job_id}/result/{cid}",
-                    _payload_bytes(rec.outcome), overwrite=False)
+                first = commit_result(self.store, job, cid,
+                                      rec.outcome.payload)
                 if first and cid not in committed:
                     committed.add(cid)
                     done_durations.append(rec.duration_s)
@@ -239,8 +239,3 @@ class Orchestrator:
                    "final_concurrency": limit},
         )
         return price_report(report)
-
-
-def _payload_bytes(outcome: InvokeOutcome) -> bytes:
-    import pickle
-    return pickle.dumps(outcome.payload)

@@ -72,9 +72,9 @@ class ServerlessFunction:
             load_s = self.store.read_time_s(n_bytes)
             if self.engine is not None:
                 params = self.store.get_tree(self.params_ref)
-                # place in the engine's planner layout on load (no-op for
-                # a meshless engine) — the serving hot path then never
-                # reshards params per invocation
+                # place on the engine's device(s) on load (the planner
+                # layout under a mesh) — the serving hot path then never
+                # re-uploads or reshards params per invocation
                 if hasattr(self.engine, "shard_params"):
                     params = self.engine.shard_params(params)
                 self._params = params

@@ -14,9 +14,9 @@ continuous-batching rows in a single dispatch.
 The per-shard block is the ``kernels/decode_attention`` Pallas kernel
 (``decode_attention_partials``) on TPU; off-TPU it runs the identical
 pure-jnp math (``decode_attention_partials_ref``) so CPU tests and
-dry-runs stay green. ``set_fused_partials`` / ``REPRO_SEQ_SHARD_FUSED``
-override the dispatch (forcing the kernel off-TPU runs it in Pallas
-interpret mode — the parity tests use exactly that).
+dry-runs stay green. ``set_fused_partials`` is a test hook: forcing the
+kernel off-TPU runs it in Pallas interpret mode, which is how the parity
+tests pin kernel against reference.
 
 Both entry points fall back to the identical single-device math when
 there is no ambient mesh, the "model" axis is trivial, or the sequence
@@ -29,27 +29,24 @@ over a (DCN) mesh axis inside a partially-manual shard_map.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.dist import compat
 from repro.dist import context as ctx
 
-# tri-state override for the Pallas-fused per-shard block:
-# None = auto (TPU only), True/False = forced (see set_fused_partials)
+# test hook for the per-shard block: None = the platform decides (kernel
+# on TPU), True/False = forced (see set_fused_partials)
 _FUSED_OVERRIDE: Optional[bool] = None
 
 
 def set_fused_partials(enabled: Optional[bool]):
-    """Force the per-shard partial-softmax implementation.
+    """Test hook: force the per-shard partial-softmax implementation.
 
     ``True`` dispatches to the Pallas kernel even off-TPU (interpret
     mode), ``False`` forces the pure-jnp reference, ``None`` restores the
-    default: kernel on TPU, jnp elsewhere. The ``REPRO_SEQ_SHARD_FUSED``
-    env var ("1"/"0") has the same effect when no override is set.
+    default: kernel on TPU, jnp elsewhere.
     """
     global _FUSED_OVERRIDE
     _FUSED_OVERRIDE = enabled
@@ -58,9 +55,6 @@ def set_fused_partials(enabled: Optional[bool]):
 def fused_partials_enabled() -> bool:
     if _FUSED_OVERRIDE is not None:
         return _FUSED_OVERRIDE
-    env = os.environ.get("REPRO_SEQ_SHARD_FUSED")
-    if env is not None:
-        return env not in ("", "0", "false", "False")
     return jax.default_backend() == "tpu"
 
 
@@ -118,10 +112,10 @@ def _shard_plan(mesh, batch: int, seq: int):
     when the sequence can't shard over "model".
 
     The data axes are always MANUAL (batch split when it divides,
-    replicated via a None spec when it doesn't): leaving them auto makes
-    the shard_map partially-manual, and ``axis_index("model")`` then
-    lowers to a PartitionId instruction jax 0.4.x SPMD rejects — hit by
-    batch-of-1 continuous-batching slots on a multi-device data axis.
+    replicated via a None spec when it doesn't), so the shard_map is
+    fully manual and ``axis_index("model")`` is a plain manual index —
+    batch-of-1 continuous-batching slots on a multi-device data axis
+    take the same path as full batches.
     """
     msize = ctx.axis_size("model", mesh)
     if mesh is None or msize <= 1 or seq % msize:
@@ -168,7 +162,7 @@ def seq_sharded_decode(q, k_cache, v_cache, lengths, *,
         den = jax.lax.psum(den * scale, "model")
         return _combine_local(q, num, den)
 
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(rep, shc, shc, P(bspec)), out_specs=rep,
         axis_names=manual, check_vma=False)(q, k_cache, v_cache, lengths)
 
@@ -209,7 +203,7 @@ def seq_sharded_write_decode(q, k_new, v_new, k_cache, v_cache, lengths, *,
         den = jax.lax.psum(den * scale, "model")
         return _combine_local(q, num, den), kc, vc
 
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(rep, rep, rep, shc, shc, P(bspec)),
         out_specs=(rep, shc, shc),

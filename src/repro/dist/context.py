@@ -14,10 +14,6 @@ from typing import Optional, Sequence
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.dist import compat
-
-compat.install()
-
 _STATE = threading.local()
 
 

@@ -2,30 +2,42 @@
 possibly RAGGED, batched KV cache.
 
 Design:
-  * grid = (batch, kv_heads, nT): the KV sequence is split into
+  * grid = (batch, kv lane blocks, nT): the KV sequence is split into
     ``block_t``-sized VMEM tiles; the trailing axis is sequential and the
     (m, l, acc) online-softmax state lives in VMEM scratch across tiles.
-  * All ``group = H/KV`` query heads of one kv head are processed together
-    as the rows of a (group, D) matmul — on the MXU this turns GQA grouping
-    into free row-parallelism instead of repeated KV reads.
-  * ``lengths`` is a (B,) vector arriving via PrefetchScalarGridSpec so the
-    index map and the in-kernel mask both see it; each batch program masks
-    against ITS OWN row's length, and the KV index map clamps the tile
-    index at that row's last valid tile — tiles strictly past
-    ``lengths[b]`` re-read the last valid tile and are fully masked, so a
-    short row in a ragged batch costs ~``lengths[b]`` of HBM traffic, not
-    ``Smax`` (the per-row early exit that makes one shared batched cache
-    cheaper than per-slot dispatches).
+  * Tiles the TPU compiler accepts. A cache (B, T, KV, D) is viewed as
+    (B, T, KV*D) — a free reshape — and one KV tile is a (block_t, W)
+    lane slab holding ``hb`` consecutive kv heads, W = hb*D. ``hb`` is 1
+    when D is a multiple of 128 (qwen2: W = 128), 128/D when the heads
+    pack a 128-lane slab exactly (distilbert's D=64: two heads per slab),
+    else all KV heads (W = KV*D, the full trailing dim). Either way the
+    block's last two dims are (8k, 128k) or full, which Mosaic requires;
+    a (block_t, 1, D) tile over the KV dim is refused.
+  * All ``group = H/KV`` query heads of the ``hb`` kv heads in a slab are
+    the rows of one (hb*group, W) matmul operand. With hb > 1 the query
+    block is block-diagonal — row group h carries its head's D values in
+    lanes [h*D, (h+1)*D) and zeros elsewhere — so ``q @ k^T`` contracts
+    each query only with its own kv head's lanes. ``p @ v`` produces all
+    W lanes per row; the wrapper keeps each row group's own D lanes.
+    At hb = 1 this is the plain (group, D) GQA matmul.
+  * Per-row bounds arrive via PrefetchScalarGridSpec as a (2, B) array of
+    (upper, lower): row b attends columns ``lower < col <= upper``. The
+    KV index map clamps the tile index at the row's last valid tile —
+    tiles strictly past ``upper`` re-read the last valid tile (no DMA)
+    and are fully masked, so a short row in a ragged batch costs about
+    ``upper`` of HBM traffic, not ``Smax``.
+  * int8 caches carry fp32 per-token-per-head scales, viewed as
+    (B, T, KV); the scale tile is (block_t, KV) (KV is the full trailing
+    dim) and the kernel expands this slab's scale columns over its lanes
+    and dequantizes the int8 tile in VMEM, so HBM traffic stays int8.
+  * Paged caches (P, page_size, KV, D) use the same kernel with a second
+    prefetched operand, the (B, Pmax) page table: the KV index map sends
+    logical tile ``ti`` of row b to physical page ``table[b, ti]``.
 
-The same (m, l, acc) merge math is reused one level up by
-``dist.collectives.seq_sharded_decode`` to combine per-chip partials of a
-sequence-sharded cache — kernel intra-chip, psum-merge inter-chip. The
-``decode_attention_partials_kernel`` variant exports exactly that seam:
-instead of normalizing at the last tile it emits the raw (acc, l, m)
-online-softmax state, in the layout ``collectives._partial_decode``
-produces, so the per-shard block of the sequence-sharded path IS this
-kernel and the cross-chip combine stays one pmax + two psums. Its bounds
-prefetch is (2, B) — per-row (upper, lower) local column bounds.
+The partials variant emits the raw (acc, l, m) online-softmax state
+instead of normalizing at the last tile: ``dist.collectives`` combines
+those per-shard partials of a sequence-sharded cache with one pmax and
+two psums.
 """
 from __future__ import annotations
 
@@ -38,58 +50,61 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128
 
 
-def _tile_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, *,
-                 ti, upper, lower, scale: float, block_t: int, group: int,
-                 softcap: Optional[float], k_scale_ref=None,
-                 v_scale_ref=None):
-    """One online-softmax step over the current (block_t, D) KV tile.
-
-    Columns attend iff ``lower < col <= upper`` (global positions are the
-    caller's concern — it folds any shard offset into the bounds).
-    Updates the (m, l, acc) VMEM scratch in place.
-
-    ``k_scale_ref``/``v_scale_ref`` (int8 KV mode) carry the per-token
-    quantization scale column for this tile — (1, block_t, 1, 1) fp32 —
-    and the int8 KV tile is dequantized HERE, in VMEM, so the kernel's
-    HBM traffic stays the int8 bytes (the halved-bandwidth win).
-    """
-    q = q_ref[0, :, 0, :].astype(jnp.float32)  # (group, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # (block_t, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    if k_scale_ref is not None:
-        k = k * k_scale_ref[0, :, 0, :]        # (block_t, 1) broadcast
-        v = v * v_scale_ref[0, :, 0, :]
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-
-    cols = ti * block_t + jax.lax.broadcasted_iota(jnp.int32,
-                                                   (group, block_t), 1)
-    mask = (cols <= upper) & (cols > lower)
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.where(m_prev > NEG_INF / 2, jnp.exp(m_prev - m_new), 0.0)
-    p = jnp.where(m_new > NEG_INF / 2, jnp.exp(s - m_new), 0.0)
-
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+def lane_heads(kv: int, d: int) -> int:
+    """kv heads per (block_t, hb*D) KV tile: the fewest whose lane width
+    is a multiple of 128, else all of them (the full trailing dim)."""
+    if d % LANES == 0:
+        return 1
+    if LANES % d == 0 and kv % (LANES // d) == 0:
+        return LANES // d
+    return kv
 
 
-def _init_scratch(m_scr, l_scr, acc_scr, ti):
-    @pl.when(ti == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+def _block_diag_q(q, kv: int, hb: int):
+    """(B, H, D) -> (B, KV/hb, hb*G, hb*D): row group h of a slab holds
+    query heads of kv head h in lanes [h*D, (h+1)*D), zeros elsewhere."""
+    b, h, d = q.shape
+    g = h // kv
+    q5 = q.reshape(b, kv // hb, hb, g, d)
+    if hb == 1:
+        return q5.reshape(b, kv, g, d)
+    eye = jnp.eye(hb, dtype=q.dtype)
+    qbd = q5[:, :, :, :, None, :] * eye[None, None, :, None, :, None]
+    return qbd.reshape(b, kv // hb, hb * g, hb * d)
+
+
+def _own_lanes(o, hb: int, g: int, d: int):
+    """(B, KV/hb, hb*G, hb*D) -> (B, KV, G, D): each row group's own
+    head lanes (the diagonal of the block-diagonal product)."""
+    b, nkb = o.shape[:2]
+    if hb == 1:
+        return o.reshape(b, nkb, g, d)
+    o6 = o.reshape(b, nkb, hb, g, hb, d)
+    od = jnp.diagonal(o6, axis1=2, axis2=4)          # (B, nkb, G, D, hb)
+    return jnp.moveaxis(od, -1, 2).reshape(b, nkb * hb, g, d)
+
+
+def _lane_scales(sc, kb, hb: int, d: int):
+    """(block_t, KV) per-head scales -> the scales of slab ``kb``'s heads,
+    as (block_t, 1) when hb == 1, else expanded to (block_t, hb*D)."""
+    bt, kv = sc.shape
+    col = jax.lax.broadcasted_iota(jnp.int32, (bt, kv), 1)
+
+    def column(head):
+        return jnp.sum(jnp.where(col == head, sc, 0.0), axis=1,
+                       keepdims=True)
+
+    if hb == 1:
+        return column(kb)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bt, hb * d), 1)
+    out = jnp.zeros((bt, hb * d), jnp.float32)
+    for h in range(hb):
+        own = (lane >= h * d) & (lane < (h + 1) * d)
+        out = jnp.where(own, column(kb * hb + h), out)
+    return out
 
 
 def _clamp_tile(ti, last_valid, block_t: int):
@@ -103,97 +118,155 @@ def _clamp_tile(ti, last_valid, block_t: int):
     return jnp.minimum(ti, jnp.maximum(last_valid, 0) // block_t)
 
 
-def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            scale: float, block_t: int, n_t: int, group: int,
-            window: Optional[int], softcap: Optional[float]):
+def _kernel(*refs, n_prefetch: int, quant: bool, partials: bool,
+            scale: float, block_t: int, n_t: int, hb: int, d: int,
+            softcap: Optional[float]):
+    bounds_ref = refs[0]
+    refs = refs[n_prefetch:]
+    q_ref, k_ref, v_ref = refs[:3]
+    refs = refs[3:]
+    if quant:
+        ks_ref, vs_ref = refs[:2]
+        refs = refs[2:]
+    n_out = 3 if partials else 1
+    outs, (m_scr, l_scr, acc_scr) = refs[:n_out], refs[n_out:]
+
     bi = pl.program_id(0)
+    kb = pl.program_id(1)
     ti = pl.program_id(2)
-    length = len_ref[bi]
-    lower = length - window if window is not None else jnp.int32(-2 ** 30)
-    _init_scratch(m_scr, l_scr, acc_scr, ti)
-    _tile_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, ti=ti,
-                 upper=length, lower=lower, scale=scale, block_t=block_t,
-                 group=group, softcap=softcap)
+    upper = bounds_ref[0, bi]
+    lower = bounds_ref[1, bi]
+
+    @pl.when(ti == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    q = q_ref[...].astype(jnp.float32)              # (rows, W)
+    k = k_ref[...].astype(jnp.float32)              # (block_t, W)
+    v = v_ref[...].astype(jnp.float32)
+    if quant:
+        k = k * _lane_scales(ks_ref[...], kb, hb, d)
+        v = v * _lane_scales(vs_ref[...], kb, hb, d)
+
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if softcap is not None:
+        s = softcap * jnp.tanh(s / softcap)
+    cols = ti * block_t + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where((cols <= upper) & (cols > lower), s, NEG_INF)
+
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.where(m_prev > NEG_INF / 2, jnp.exp(m_prev - m_new), 0.0)
+    p = jnp.where(m_new > NEG_INF / 2, jnp.exp(s - m_new), 0.0)
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    m_scr[...] = m_new
 
     @pl.when(ti == n_t - 1)
     def _done():
-        l = l_scr[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l).astype(o_ref.dtype)
+        if partials:
+            o_ref, m_ref, l_ref = outs
+            o_ref[...] = acc_scr[...]
+            m_ref[...] = m_scr[...]
+            l_ref[...] = l_scr[...]
+        else:
+            l = l_scr[...]
+            l = jnp.where(l == 0.0, 1.0, l)
+            outs[0][...] = (acc_scr[...] / l).astype(outs[0].dtype)
 
 
-def _kernel_partials(bounds_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                     m_scr, l_scr, acc_scr, *, scale: float, block_t: int,
-                     n_t: int, group: int, softcap: Optional[float]):
-    """Same tile loop as ``_kernel`` but emits raw (acc, l, m) partials.
+def _flash_decode(q, k, v, k_scale, v_scale, bounds, table, *,
+                  block_t: int, softcap: Optional[float], partials: bool,
+                  interpret: bool, name: str):
+    """Shared pallas_call for every decode variant.
 
-    ``bounds_ref`` prefetches a (2, B) array of per-row (upper, lower)
-    LOCAL column bounds with the sequence-shard offset already subtracted,
-    so a shard that owns no valid position for row b (upper < 0) produces
-    the neutral element (acc=0, l=0, m=NEG_INF) for that row and drops out
-    of the cross-shard combine.
+    q: (B,H,D); k/v: (N, T, KV, D) — N = B for a dense cache, the page
+    pool size for a paged one (then ``table`` is (B, Pmax) and
+    ``block_t`` is the page size); scales: (N, T, KV, 1) fp32 or None;
+    bounds: (2, B) int32 per-row (upper, lower). Returns (B, KV, G, D)
+    in q's dtype, or fp32 (acc (B,KV,G,D), l (B,KV,G), m (B,KV,G)) when
+    ``partials``.
     """
-    bi = pl.program_id(0)
-    ti = pl.program_id(2)
-    _init_scratch(m_scr, l_scr, acc_scr, ti)
-    _tile_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, ti=ti,
-                 upper=bounds_ref[0, bi], lower=bounds_ref[1, bi],
-                 scale=scale, block_t=block_t, group=group, softcap=softcap)
+    b, h, d = q.shape
+    n, t, kv = k.shape[:3]
+    g = h // kv
+    hb = lane_heads(kv, d)
+    nkb, rows, w = kv // hb, hb * g, hb * d
+    paged = table is not None
+    n_t = table.shape[1] if paged else t // block_t
+    quant = k_scale is not None
 
-    @pl.when(ti == n_t - 1)
-    def _done():
-        o_ref[...] = acc_scr[...].reshape(o_ref.shape)
-        m_ref[...] = m_scr[...].reshape(m_ref.shape)
-        l_ref[...] = l_scr[...].reshape(l_ref.shape)
+    def tile(bi, ti, pref):
+        ti = _clamp_tile(ti, pref[0][0, bi], block_t)
+        return (pref[1][bi, ti], 0) if paged else (bi, ti)
+
+    def kv_map(bi, kb, ti, *pref):
+        return tile(bi, ti, pref) + (kb,)
+
+    def scale_map(bi, kb, ti, *pref):
+        return tile(bi, ti, pref) + (0,)
+
+    def row_map(bi, kb, ti, *pref):
+        return (bi, kb, 0, 0)
+
+    kv_spec = pl.BlockSpec((None, block_t, w), kv_map)
+    row_spec = pl.BlockSpec((None, None, rows, w), row_map)
+    in_specs = [row_spec, kv_spec, kv_spec]
+    operands = [_block_diag_q(q, kv, hb), k.reshape(n, t, kv * d),
+                v.reshape(n, t, kv * d)]
+    if quant:
+        in_specs += [pl.BlockSpec((None, block_t, kv), scale_map)] * 2
+        operands += [k_scale.reshape(n, t, kv), v_scale.reshape(n, t, kv)]
+    if partials:
+        col_spec = pl.BlockSpec((None, None, rows, 1), row_map)
+        out_specs = [row_spec, col_spec, col_spec]
+        out_shape = [jax.ShapeDtypeStruct((b, nkb, rows, w), jnp.float32),
+                     jax.ShapeDtypeStruct((b, nkb, rows, 1), jnp.float32),
+                     jax.ShapeDtypeStruct((b, nkb, rows, 1), jnp.float32)]
+    else:
+        out_specs = row_spec
+        out_shape = jax.ShapeDtypeStruct((b, nkb, rows, w), q.dtype)
+    prefetch = [jnp.asarray(bounds, jnp.int32)]
+    if paged:
+        prefetch.append(jnp.asarray(table, jnp.int32))
+
+    kernel = functools.partial(
+        _kernel, n_prefetch=len(prefetch), quant=quant, partials=partials,
+        scale=1.0 / (d ** 0.5), block_t=block_t, n_t=n_t, hb=hb, d=d,
+        softcap=softcap)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(b, nkb, n_t),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, w), jnp.float32)]),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(*prefetch, *operands)
+    if not partials:
+        return _own_lanes(out, hb, g, d)
+    acc, m, l = out
+    return (_own_lanes(acc, hb, g, d), l.reshape(b, kv, g),
+            m.reshape(b, kv, g))
 
 
-def _kernel_quant(len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, scale: float, block_t: int,
-                  n_t: int, group: int, window: Optional[int],
-                  softcap: Optional[float]):
-    """int8-KV variant of ``_kernel``: same tile loop and mask math, with
-    the per-token scale columns riding beside the KV tiles and the
-    dequantize fused into ``_tile_update`` (int8 bytes over HBM, fp32
-    math in VMEM)."""
-    bi = pl.program_id(0)
-    ti = pl.program_id(2)
-    length = len_ref[bi]
-    lower = length - window if window is not None else jnp.int32(-2 ** 30)
-    _init_scratch(m_scr, l_scr, acc_scr, ti)
-    _tile_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, ti=ti,
-                 upper=length, lower=lower, scale=scale, block_t=block_t,
-                 group=group, softcap=softcap, k_scale_ref=ks_ref,
-                 v_scale_ref=vs_ref)
-
-    @pl.when(ti == n_t - 1)
-    def _done():
-        l = l_scr[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l).astype(o_ref.dtype)
-
-
-def _kernel_paged_quant(len_ref, table_ref, q_ref, k_ref, v_ref, ks_ref,
-                        vs_ref, o_ref, m_scr, l_scr, acc_scr, **kw):
-    """Paged int8 variant: page-table indirection in the index map (as in
-    ``_kernel_paged``), per-page scale columns DMA'd beside the pages."""
-    del table_ref
-    _kernel_quant(len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-                  m_scr, l_scr, acc_scr, **kw)
-
-
-def _kernel_paged(len_ref, table_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
-                  l_scr, acc_scr, **kw):
-    """Paged variant of ``_kernel``: identical tile loop and mask math.
-
-    The page table participates ONLY in the KV index map (the grid spec
-    prefetches it alongside ``lengths``); inside the kernel body the tile
-    index ``ti`` is already the row's LOGICAL page, so the column mask is
-    the same ``ti * block_t + iota`` arithmetic as the dense kernel —
-    physical indirection is invisible to the math.
-    """
-    del table_ref
-    _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-            **kw)
+def _length_bounds(lengths, window: Optional[int]):
+    """(B,) current indices -> (2, B) (upper, lower) column bounds."""
+    lengths = jnp.asarray(lengths, jnp.int32)
+    lower = (lengths - window if window is not None
+             else jnp.full_like(lengths, -2 ** 30))
+    return jnp.stack([lengths, lower])
 
 
 @functools.partial(
@@ -206,49 +279,11 @@ def decode_attention_kernel(q, k_cache, v_cache, lengths, *,
     """q: (B,H,D); caches: (B,T,KV,D), T % block_t == 0; lengths: (B,)
     int32 — row b attends kv positions <= lengths[b]."""
     b, h, d = q.shape
-    t, kv = k_cache.shape[1], k_cache.shape[2]
-    group = h // kv
-    n_t = t // block_t
-    scale = 1.0 / (d ** 0.5)
-
-    # view q as (B, KV, group, D) so one program owns one kv head's group
-    qg = q.reshape(b, kv, group, d).transpose(0, 2, 1, 3)  # (B, group, KV, D)
-
-    kernel = functools.partial(
-        _kernel, scale=scale, block_t=block_t, n_t=n_t, group=group,
-        window=window, softcap=softcap)
-
-    def kv_map(bi, ki, ti, lens):
-        return (bi, _clamp_tile(ti, lens[bi], block_t), ki, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, kv, n_t),
-        in_specs=[
-            pl.BlockSpec((1, group, 1, d),
-                         lambda bi, ki, ti, lens: (bi, 0, ki, 0)),
-            pl.BlockSpec((1, block_t, 1, d), kv_map),
-            pl.BlockSpec((1, block_t, 1, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, group, 1, d),
-                               lambda bi, ki, ti, lens: (bi, 0, ki, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, d), jnp.float32),
-        ],
-    )
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, group, kv, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="decode_attention",
-    )(jnp.asarray(lengths, jnp.int32), qg, k_cache, v_cache)
-    return out.transpose(0, 2, 1, 3).reshape(b, h, d)
+    out = _flash_decode(q, k_cache, v_cache, None, None,
+                        _length_bounds(lengths, window), None,
+                        block_t=block_t, softcap=softcap, partials=False,
+                        interpret=interpret, name="decode_attention")
+    return out.reshape(b, h, d)
 
 
 @functools.partial(
@@ -261,55 +296,14 @@ def decode_attention_quant_kernel(q, k_cache, v_cache, k_scale, v_scale,
                                   interpret: bool = False):
     """int8-KV flash decode. q: (B,H,D) fp; caches: (B,T,KV,D) int8;
     scales: (B,T,KV,1) fp32 (per-token-per-kv-head); lengths: (B,) int32.
-    Same grid/index-map/early-exit structure as ``decode_attention_kernel``
-    — the scale columns use the SAME clamped KV index map, so a short
-    row's HBM traffic stays ~lengths[b] of int8 bytes + scales."""
+    The scale tiles use the same clamped index map as the KV tiles, so a
+    short row's HBM traffic stays ~lengths[b] of int8 bytes + scales."""
     b, h, d = q.shape
-    t, kv = k_cache.shape[1], k_cache.shape[2]
-    group = h // kv
-    n_t = t // block_t
-    scale = 1.0 / (d ** 0.5)
-
-    qg = q.reshape(b, kv, group, d).transpose(0, 2, 1, 3)  # (B, group, KV, D)
-
-    kernel = functools.partial(
-        _kernel_quant, scale=scale, block_t=block_t, n_t=n_t, group=group,
-        window=window, softcap=softcap)
-
-    def kv_map(bi, ki, ti, lens):
-        return (bi, _clamp_tile(ti, lens[bi], block_t), ki, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, kv, n_t),
-        in_specs=[
-            pl.BlockSpec((1, group, 1, d),
-                         lambda bi, ki, ti, lens: (bi, 0, ki, 0)),
-            pl.BlockSpec((1, block_t, 1, d), kv_map),
-            pl.BlockSpec((1, block_t, 1, d), kv_map),
-            pl.BlockSpec((1, block_t, 1, 1), kv_map),
-            pl.BlockSpec((1, block_t, 1, 1), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, group, 1, d),
-                               lambda bi, ki, ti, lens: (bi, 0, ki, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, d), jnp.float32),
-        ],
-    )
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, group, kv, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="decode_attention_int8_kv",
-    )(jnp.asarray(lengths, jnp.int32), qg, k_cache, v_cache, k_scale,
-      v_scale)
-    return out.transpose(0, 2, 1, 3).reshape(b, h, d)
+    out = _flash_decode(q, k_cache, v_cache, k_scale, v_scale,
+                        _length_bounds(lengths, window), None,
+                        block_t=block_t, softcap=softcap, partials=False,
+                        interpret=interpret, name="decode_attention_int8_kv")
+    return out.reshape(b, h, d)
 
 
 @functools.partial(
@@ -327,60 +321,10 @@ def decode_attention_partials_kernel(q, k_cache, v_cache, bounds, *,
     ``(num (B,KV,G,D), den (B,KV,G), m (B,KV,G))`` matching
     ``decode_attention_partials_ref``.
     """
-    b, h, d = q.shape
-    t, kv = k_cache.shape[1], k_cache.shape[2]
-    group = h // kv
-    n_t = t // block_t
-    scale = 1.0 / (d ** 0.5)
-
-    qg = q.reshape(b, kv, group, d).transpose(0, 2, 1, 3)  # (B, group, KV, D)
-
-    kernel = functools.partial(
-        _kernel_partials, scale=scale, block_t=block_t, n_t=n_t,
-        group=group, softcap=softcap)
-
-    def kv_map(bi, ki, ti, bounds):
-        return (bi, _clamp_tile(ti, bounds[0, bi], block_t), ki, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, kv, n_t),
-        in_specs=[
-            pl.BlockSpec((1, group, 1, d),
-                         lambda bi, ki, ti, bounds: (bi, 0, ki, 0)),
-            pl.BlockSpec((1, block_t, 1, d), kv_map),
-            pl.BlockSpec((1, block_t, 1, d), kv_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, group, 1, d),
-                         lambda bi, ki, ti, bounds: (bi, 0, ki, 0)),
-            pl.BlockSpec((1, group, 1),
-                         lambda bi, ki, ti, bounds: (bi, 0, ki)),
-            pl.BlockSpec((1, group, 1),
-                         lambda bi, ki, ti, bounds: (bi, 0, ki)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, d), jnp.float32),
-        ],
-    )
-
-    acc, m, l = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, group, kv, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, group, kv), jnp.float32),
-            jax.ShapeDtypeStruct((b, group, kv), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="decode_attention_partials",
-    )(jnp.asarray(bounds, jnp.int32), qg, k_cache, v_cache)
-    return (acc.transpose(0, 2, 1, 3), l.transpose(0, 2, 1),
-            m.transpose(0, 2, 1))
+    return _flash_decode(q, k_cache, v_cache, None, None, bounds, None,
+                         block_t=block_t, softcap=softcap, partials=True,
+                         interpret=interpret,
+                         name="decode_attention_partials")
 
 
 @functools.partial(
@@ -400,56 +344,17 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, lengths, page_table,
     The KV tile is one page: the grid's trailing axis walks logical
     pages and the KV index map reads the scalar-prefetched page table to
     DMA the matching physical page, clamped at the row's last valid page
-    (the same per-row HBM early exit as the dense ragged kernel — a
-    short row costs ~lengths[b] of traffic regardless of pool size).
-    Rows sharing prefix pages DMA the SAME physical tiles; no dense
-    per-row view ever materializes.
+    (the same per-row HBM early exit as the dense ragged kernel). Rows
+    sharing prefix pages DMA the SAME physical tiles; no dense per-row
+    view ever materializes.
     """
     b, h, d = q.shape
-    ps, kv = k_pages.shape[1], k_pages.shape[2]
-    n_t = page_table.shape[1]
-    group = h // kv
-    scale = 1.0 / (d ** 0.5)
-
-    qg = q.reshape(b, kv, group, d).transpose(0, 2, 1, 3)  # (B, group, KV, D)
-
-    kernel = functools.partial(
-        _kernel_paged, scale=scale, block_t=ps, n_t=n_t, group=group,
-        window=window, softcap=softcap)
-
-    def kv_map(bi, ki, ti, lens, table):
-        return (table[bi, _clamp_tile(ti, lens[bi], ps)], 0, ki, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, kv, n_t),
-        in_specs=[
-            pl.BlockSpec((1, group, 1, d),
-                         lambda bi, ki, ti, lens, table: (bi, 0, ki, 0)),
-            pl.BlockSpec((1, ps, 1, d), kv_map),
-            pl.BlockSpec((1, ps, 1, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, group, 1, d),
-                               lambda bi, ki, ti, lens, table:
-                               (bi, 0, ki, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, d), jnp.float32),
-        ],
-    )
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, group, kv, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="paged_decode_attention",
-    )(jnp.asarray(lengths, jnp.int32), jnp.asarray(page_table, jnp.int32),
-      qg, k_pages, v_pages)
-    return out.transpose(0, 2, 1, 3).reshape(b, h, d)
+    out = _flash_decode(q, k_pages, v_pages, None, None,
+                        _length_bounds(lengths, window), page_table,
+                        block_t=k_pages.shape[1], softcap=softcap,
+                        partials=False, interpret=interpret,
+                        name="paged_decode_attention")
+    return out.reshape(b, h, d)
 
 
 @functools.partial(
@@ -465,49 +370,9 @@ def paged_decode_attention_quant_kernel(q, k_pages, v_pages, k_scale,
     SAME scalar-prefetched page table (and COW page copies / shared
     prefix pages move scales with their data for free)."""
     b, h, d = q.shape
-    ps, kv = k_pages.shape[1], k_pages.shape[2]
-    n_t = page_table.shape[1]
-    group = h // kv
-    scale = 1.0 / (d ** 0.5)
-
-    qg = q.reshape(b, kv, group, d).transpose(0, 2, 1, 3)  # (B, group, KV, D)
-
-    kernel = functools.partial(
-        _kernel_paged_quant, scale=scale, block_t=ps, n_t=n_t, group=group,
-        window=window, softcap=softcap)
-
-    def kv_map(bi, ki, ti, lens, table):
-        return (table[bi, _clamp_tile(ti, lens[bi], ps)], 0, ki, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, kv, n_t),
-        in_specs=[
-            pl.BlockSpec((1, group, 1, d),
-                         lambda bi, ki, ti, lens, table: (bi, 0, ki, 0)),
-            pl.BlockSpec((1, ps, 1, d), kv_map),
-            pl.BlockSpec((1, ps, 1, d), kv_map),
-            pl.BlockSpec((1, ps, 1, 1), kv_map),
-            pl.BlockSpec((1, ps, 1, 1), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, group, 1, d),
-                               lambda bi, ki, ti, lens, table:
-                               (bi, 0, ki, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, d), jnp.float32),
-        ],
-    )
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, group, kv, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="paged_decode_attention_int8_kv",
-    )(jnp.asarray(lengths, jnp.int32), jnp.asarray(page_table, jnp.int32),
-      qg, k_pages, v_pages, k_scale, v_scale)
-    return out.transpose(0, 2, 1, 3).reshape(b, h, d)
+    out = _flash_decode(q, k_pages, v_pages, k_scale, v_scale,
+                        _length_bounds(lengths, window), page_table,
+                        block_t=k_pages.shape[1], softcap=softcap,
+                        partials=False, interpret=interpret,
+                        name="paged_decode_attention_int8_kv")
+    return out.reshape(b, h, d)

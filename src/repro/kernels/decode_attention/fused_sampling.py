@@ -14,12 +14,12 @@ Three layers, all with bit-identical semantics to the host sampler:
     ``categorical(key, apply_filters(logits, ...))``, so parity between
     the fused and host paths is by construction, not by test luck.
   * :func:`fused_sample_kernel` — the Pallas TPU epilogue kernel: one
-    program per batch row does temperature scaling, an in-kernel top-k
-    threshold (a count-above-threshold ``while_loop`` — NO vocab sort,
-    and it reproduces ``jax.lax.top_k``'s duplicate/tie semantics), the
-    top-p nucleus mask, and the Gumbel-argmax draw. Two inputs the
-    kernel cannot produce portably are computed by XLA ops INSIDE the
-    same jit executable and passed in: the per-row nucleus cutoff
+    program per block of 8 batch rows does temperature scaling, an
+    in-kernel top-k threshold (a count-above-threshold ``while_loop`` —
+    NO vocab sort, and it reproduces ``jax.lax.top_k``'s duplicate/tie
+    semantics), the top-p nucleus mask, and the Gumbel-argmax draw. Two
+    inputs the kernel cannot produce portably are computed by XLA ops
+    INSIDE the same jit executable and passed in: the per-row nucleus cutoff
     probability (needs a vocab sort) and the Gumbel noise (must come
     from ``jax.random`` so the draw matches the host sampler's
     ``categorical`` bit-for-bit — ``categorical(key, z)`` IS
@@ -44,6 +44,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+import jax.experimental.pallas.tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -108,32 +109,34 @@ def nucleus_cutoff(logits, top_p: float):
 
 
 def _topk_threshold(z, k: int):
-    """The k-th largest value of ``z`` (1, V) WITHOUT sorting.
+    """Per-row k-th largest value of ``z`` (R, V) WITHOUT sorting, (R, 1).
 
-    Iterates (t, n) where ``n = count(z >= t)``: start at the row max
-    and walk t down to the next distinct value until at least k entries
-    clear it. Terminates in <= k steps (each step admits >= 1 new
-    entry), each step a vector compare+reduce — O(kV) worst case, no
-    sort. With duplicates the returned threshold equals
-    ``jax.lax.top_k(z, k)[0][..., -1]``: the count may exceed k, and
-    every tie at the threshold survives the ``z < t`` mask — exactly the
-    host sampler's semantics.
+    Iterates (t, n) where ``n = count(z >= t)`` per row: start at the row
+    max and walk t down to the next distinct value until at least k
+    entries clear it. A row that has reached k keeps its threshold while
+    the others walk on. Terminates in <= k steps (each step admits >= 1
+    new entry per unfinished row), each step a vector compare+reduce —
+    O(kV) worst case, no sort. With duplicates the returned threshold
+    equals ``jax.lax.top_k(z, k)[0][..., -1]``: the count may exceed k,
+    and every tie at the threshold survives the ``z < t`` mask — exactly
+    the host sampler's semantics.
     """
     fmin = jnp.finfo(jnp.float32).min
 
     def count_ge(t):
-        return jnp.sum((z >= t).astype(jnp.int32))
+        return jnp.sum((z >= t).astype(jnp.int32), axis=1, keepdims=True)
 
-    t0 = jnp.max(z)
+    t0 = jnp.max(z, axis=1, keepdims=True)
 
     def cond(carry):
         _, n = carry
-        return n < k
+        return jnp.any(n < k)
 
     def body(carry):
-        t, _ = carry
-        t2 = jnp.max(jnp.where(z < t, z, fmin))
-        return t2, count_ge(t2)
+        t, n = carry
+        t2 = jnp.max(jnp.where(z < t, z, fmin), axis=1, keepdims=True)
+        t = jnp.where(n < k, t2, t)
+        return t, count_ge(t)
 
     t, _ = jax.lax.while_loop(cond, body, (t0, count_ge(t0)))
     return t
@@ -142,27 +145,31 @@ def _topk_threshold(z, k: int):
 def _sample_kernel(logits_ref, gumbel_ref, cutoff_ref, tok_ref, *,
                    temperature: float, top_k: Optional[int],
                    use_top_p: bool):
-    """One batch row: filter logits in VMEM, Gumbel-argmax, emit int32.
+    """A block of rows: filter logits in VMEM, Gumbel-argmax, emit int32.
 
-    The (1, V) logits tile never leaves VMEM — the only HBM write is the
-    sampled token id. ``gumbel_ref`` carries the ``jax.random`` noise
+    The (R, V) logits tile never leaves VMEM — the only HBM write is the
+    sampled token ids. ``gumbel_ref`` carries the ``jax.random`` noise
     and ``cutoff_ref`` the per-row nucleus cutoff (see module docstring
     for why those two are produced outside the kernel body).
     """
-    z = logits_ref[...].astype(jnp.float32) / temperature  # (1, V)
+    z = logits_ref[...].astype(jnp.float32) / temperature  # (R, V)
     if top_k is not None:
-        kth = _topk_threshold(z, top_k)
-        z = jnp.where(z < kth, NEG_INF, z)
+        z = jnp.where(z < _topk_threshold(z, top_k), NEG_INF, z)
     if use_top_p:
         # same softmax form as jax.nn.softmax: exp(z - max) / sum
         e = jnp.exp(z - jnp.max(z, axis=1, keepdims=True))
         p = e / jnp.sum(e, axis=1, keepdims=True)
-        z = jnp.where(p < cutoff_ref[0, 0], NEG_INF, z)
+        z = jnp.where(p < cutoff_ref[...], NEG_INF, z)
     y = z + gumbel_ref[...].astype(jnp.float32)
     # argmax = FIRST index attaining the max (2D iota per the TPU rule)
     idx = jax.lax.broadcasted_iota(jnp.int32, y.shape, 1)
     hit = y == jnp.max(y, axis=1, keepdims=True)
-    tok_ref[0, 0] = jnp.min(jnp.where(hit, idx, jnp.iinfo(jnp.int32).max))
+    tok_ref[...] = jnp.min(jnp.where(hit, idx, jnp.iinfo(jnp.int32).max),
+                           axis=1, keepdims=True)
+
+
+ROW_BLOCK = 8            # sublane multiple: the block's second-minor dim
+VMEM_LIMIT = 96 * 2**20  # v5e has 128 MiB of VMEM; the default scope is 16
 
 
 @functools.partial(
@@ -174,24 +181,36 @@ def fused_sample_kernel(logits, gumbel, cutoff, *, temperature: float,
     """Pallas sampling epilogue. logits/gumbel: (B, V); cutoff: (B, 1)
     fp32 (ignored unless ``use_top_p``). Returns (B,) int32 token ids.
     Requires ``temperature > 0`` (greedy is a plain argmax — no kernel).
+
+    One program samples ``ROW_BLOCK`` rows over the whole vocab (the
+    top-k walk and the nucleus softmax need the full row); B is padded
+    to a multiple of ``ROW_BLOCK`` and the pad rows are dropped. At
+    vocab 152,064 a block is 4.9 MB per fp32 input, double-buffered, plus
+    the filter's temporaries — over the default scoped VMEM, so the
+    kernel asks for ``VMEM_LIMIT``.
     """
     b, v = logits.shape
+    pad = (-b) % ROW_BLOCK
+    cutoff = jnp.asarray(cutoff, jnp.float32)
+    if pad:
+        logits, gumbel, cutoff = (jnp.pad(x, ((0, pad), (0, 0)))
+                                  for x in (logits, gumbel, cutoff))
     kernel = functools.partial(_sample_kernel, temperature=temperature,
                                top_k=top_k, use_top_p=use_top_p)
+    row = pl.BlockSpec((ROW_BLOCK, v), lambda bi: (bi, 0))
+    col = pl.BlockSpec((ROW_BLOCK, 1), lambda bi: (bi, 0))
     out = pl.pallas_call(
         kernel,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, v), lambda bi: (bi, 0)),
-            pl.BlockSpec((1, v), lambda bi: (bi, 0)),
-            pl.BlockSpec((1, 1), lambda bi: (bi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda bi: (bi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
+        grid=((b + pad) // ROW_BLOCK,),
+        in_specs=[row, row, col],
+        out_specs=col,
+        out_shape=jax.ShapeDtypeStruct((b + pad, 1), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="fused_sampling_epilogue",
-    )(logits, gumbel, jnp.asarray(cutoff, jnp.float32))
-    return out[:, 0]
+    )(logits, gumbel, cutoff)
+    return out[:b, 0]
 
 
 # ---------------------------------------------------------------------------

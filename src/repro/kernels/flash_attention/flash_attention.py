@@ -4,8 +4,15 @@ Design (TPU-native, see DESIGN.md §5):
   * grid = (batch, q_heads, nQ, nK); the trailing nK axis is "arbitrary"
     (sequential) so the online-softmax running state lives in VMEM scratch
     across k-blocks.
-  * BlockSpecs tile q/out as (1, block_q, 1, D) and k/v as (1, block_k, 1, D)
-    — block_q/block_k default 128 to align the MXU contraction lanes.
+  * Every tile is (block_q, D) of q/out or (block_k, D) of k/v: a
+    sequence tile by one head. Mosaic refuses a (block, 1, D) tile over
+    a (B, S, H, D) array (an extent-1 head dim second-minor), so the
+    head dim leaves the tile's last two dims one of two ways. When D is
+    a multiple of 128 the arrays are viewed as (B, S, H*D) lane slabs —
+    a free reshape, the layout the decode kernels use — and head h is
+    lane block h. Otherwise the wrapper transposes to head-major
+    (B, H, S, D) and back (prefill activations, not the KV cache).
+    block_q/block_k default 128 to align the MXU contraction lanes.
   * GQA is handled in the k/v index_map (kv_head = q_head // group) — no
     repeated-KV materialization in HBM.
   * Causal / sliding-window masks are applied from global iota offsets;
@@ -39,9 +46,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)  # (bq, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[...].astype(jnp.float32)  # (bq, D)
+    k = k_ref[...].astype(jnp.float32)  # (bk, D)
+    v = v_ref[...].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -77,7 +84,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     def _done():
         l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)  # rows with no visible keys -> 0 out
-        o_ref[0, :, 0, :] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -102,20 +109,28 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True,
         causal=causal, window=window, softcap=softcap,
         t_valid=t_valid if t_valid is not None else t)
 
-    return pl.pallas_call(
+    if d % 128 == 0:       # lane slabs: (B, S, H*D), head h = lane block h
+        q_spec = pl.BlockSpec((None, block_q, d),
+                              lambda bi, hi, qi, ki: (bi, qi, hi))
+        kv_spec = pl.BlockSpec((None, block_k, d),
+                               lambda bi, hi, qi, ki: (bi, ki, hi // group))
+        out_shape = (b, s, h * d)
+        args = (q.reshape(b, s, h * d), k.reshape(b, t, kv * d),
+                v.reshape(b, t, kv * d))
+    else:                  # head-major: (B, H, S, D)
+        q_spec = pl.BlockSpec((None, None, block_q, d),
+                              lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+        kv_spec = pl.BlockSpec((None, None, block_k, d),
+                               lambda bi, hi, qi, ki: (bi, hi // group, ki, 0))
+        out_shape = (b, h, s, d)
+        args = (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                v.transpose(0, 2, 1, 3))
+    out = pl.pallas_call(
         kernel,
         grid=(b, h, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, d),
-                         lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda bi, hi, qi, ki: (bi, ki, hi // group, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda bi, hi, qi, ki: (bi, ki, hi // group, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, d),
-                               lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s, h, d), q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -126,4 +141,7 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True,
                                  "arbitrary")),
         interpret=interpret,
         name="flash_attention",
-    )(q, k, v)
+    )(*args)
+    if d % 128 == 0:
+        return out.reshape(b, s, h, d)
+    return out.transpose(0, 2, 1, 3)

@@ -217,8 +217,6 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool = False,
             rec["memory"]["live_bytes_per_chip"] = int(live)
             rec["memory"]["fits_16g_hbm"] = bool(live <= 16 * 2**30)
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # jax 0.4.x: one dict per program
-            ca = ca[0] if ca else {}
         rec["cost_analysis"] = {
             "flops_scan_once": float(ca.get("flops", 0.0)),
             "bytes_scan_once": float(ca.get("bytes accessed", 0.0)),

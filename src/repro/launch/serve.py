@@ -26,7 +26,8 @@ replica pools — heterogeneous spot/on-demand placement, deterministic
 preemption survival with bit-identical outputs, optional ``--chaos``
 ladder.
 
-Usage:
+Usage (published widths; on a CPU add ``--arch distilbert-imdb-smoke``
+or ``--router-arch qwen2-7b-smoke``; full qwen2-7b needs ``--mesh 1x4``):
   python -m repro.launch.serve --n-items 256 --batch-size 32 \
       --concurrency 8 --crash-prob 0.1
   python -m repro.launch.serve --batch-dag --dag-workers 6 \
@@ -44,13 +45,19 @@ Mesh mode: ``--mesh DxM`` (e.g. ``--mesh 2x4`` over 8 host devices, or
 on TPU the real chips) lays a ("data", "model") mesh under every worker's
 engine — params in the planner layout, inputs batch-sharded, and with
 ``--seq-shard`` the decode KV cache sequence-sharded over "model".
+
+Models: ``--arch`` / ``--router-arch`` name a published config at its
+published widths (``repro.configs.get``); ``<arch>-smoke`` names its
+tiny-width preset for CPU runs, e.g. ``--router-arch qwen2-7b-smoke``.
+Each mode is a function of (args, [mesh,] resolved ``ModelConfig``), so
+a caller can run it with a config of its own (a depth cut, say).
+Weights are random, made from ``--seed`` on the device(s).
 """
 from __future__ import annotations
 
 import argparse
 
 import jax
-import numpy as np
 
 from repro import configs
 from repro.core import (ArtifactStore, BatchJob, FaultInjector,
@@ -59,11 +66,12 @@ from repro.core import (ArtifactStore, BatchJob, FaultInjector,
                         ServerlessFunction, decompose, merge)
 from repro.data import imdb_reviews
 from repro.data.pipeline import DatasetRef
-from repro.models import RunConfig, build
+from repro.launch import compile_cache
+from repro.models import ModelConfig, RunConfig, build
 from repro.serving import Engine
 
 
-def run_router(args, mesh):
+def run_router(args, mesh, cfg: ModelConfig):
     """Online mode: live traffic, per-policy TTFT/TPOT/cost rows.
     Also the home of ``--calibrate`` (measure + fit + save the round
     model on this host's engine, then use it if ``--router``)."""
@@ -73,12 +81,10 @@ def run_router(args, mesh):
                               fit_round_model, make_requests,
                               measure_round_samples)
 
-    cfg = configs.smoke(args.router_arch)
-    model = build(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
-    engine = Engine(model, RunConfig(cache_pad=16, kv_dtype=args.kv_dtype),
+    engine = Engine(build(cfg),
+                    RunConfig(cache_pad=16, kv_dtype=args.kv_dtype),
                     mesh=mesh, seq_shard=args.seq_shard)
-    params = engine.shard_params(params)
+    params = engine.init_params(args.seed)
     store = ArtifactStore()
     store.put_tree("models/lm", params)
 
@@ -160,7 +166,7 @@ def run_router(args, mesh):
     return out
 
 
-def run_batch_dag(args):
+def run_batch_dag(args, cfg: ModelConfig):
     """Batch-DAG mode: the offline job as an explicit
     shard→prefill→decode→reduce DAG on cloud-profiled replica pools
     (repro.batch) — monolithic vs parallel, spot preemptions survived
@@ -171,10 +177,8 @@ def run_batch_dag(args):
     from repro.router.cloud import ON_DEMAND, spot_profile
     from repro.router.events import VirtualClock
 
-    cfg = configs.smoke(args.router_arch)
-    model = build(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
-    engine = Engine(model, RunConfig(cache_pad=8))
+    engine = Engine(build(cfg), RunConfig(cache_pad=8))
+    params = engine.init_params(args.seed)
     data = make_dataset(args.dag_items, prompt_len=args.prompt_len,
                         vocab=cfg.vocab_size,
                         max_new_tokens=args.max_new_tokens, seed=args.seed)
@@ -244,9 +248,13 @@ def run_batch_dag(args):
     return out
 
 
-def run_http(args, mesh):
+def run_http(args, mesh, cfg: ModelConfig, until=None):
     """Live HTTP mode: the asyncio front door over the event-driven
-    router (wall clock, measured TTFT). Serves until interrupted."""
+    router (wall clock, measured TTFT). Serves until interrupted, or —
+    given ``until``, an ``async`` callable of the started front door —
+    until that coroutine returns; its result is returned as
+    ``"clients"``. With ``--mesh-slices`` each replica holds its own
+    slice of ``mesh`` (``"replica_devices"`` lists their device ids)."""
     import asyncio
 
     from repro.core import LatencyModel
@@ -255,12 +263,10 @@ def run_http(args, mesh):
                               QueueDepthPolicy, ReplicaConfig, ReplicaPool,
                               WallClock)
 
-    cfg = configs.smoke(args.router_arch)
-    model = build(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
-    engine = Engine(model, RunConfig(cache_pad=16, kv_dtype=args.kv_dtype),
+    engine = Engine(build(cfg),
+                    RunConfig(cache_pad=16, kv_dtype=args.kv_dtype),
                     mesh=mesh, seq_shard=args.seq_shard)
-    params = engine.shard_params(params)
+    params = engine.init_params(args.seed)
     pool = ReplicaPool(
         engine, params,
         ReplicaConfig(n_slots=args.n_slots,
@@ -270,7 +276,8 @@ def run_http(args, mesh):
         # the virtual harness's business (EventRouter raises on both)
         lat=LatencyModel(cold_start_s=args.cold_start, per_item_s=None),
         injector=FaultInjector(seed=args.seed, crash_prob=args.crash_prob,
-                               straggler_prob=args.straggler_prob))
+                               straggler_prob=args.straggler_prob),
+        mesh_slices=args.mesh_slices)
     obs = Observability(
         tracer=TraceRecorder() if args.trace else None)
     router = EventRouter(
@@ -287,7 +294,9 @@ def run_http(args, mesh):
               f"POST /v1/generate, GET /healthz, GET /metrics "
               f"(Prometheus), GET /metrics.json ==")
         try:
-            await asyncio.Event().wait()      # until Ctrl-C
+            if until is None:
+                await asyncio.Event().wait()      # until Ctrl-C
+            return await until(door)
         finally:
             await door.close()
             print(router.report().format_line())
@@ -297,14 +306,86 @@ def run_http(args, mesh):
                       f"(analyze: python tools/trace_report.py "
                       f"{args.trace}) ==")
 
+    out = {"port": door.port}
     try:
-        asyncio.run(_serve())
+        out["clients"] = asyncio.run(_serve())
     except KeyboardInterrupt:
         pass
-    return {"port": door.port}
+    out["report"] = router.report().summary()
+    if pool.slices is not None:
+        # which devices each replica ever spawned served on
+        out["replica_devices"] = [
+            [d.id for d in pool.slices.devices_of(r.slice_idx)]
+            for r in pool.replicas]
+    return out
 
 
-def main(argv=None):
+def run_offline(args, mesh, cfg: ModelConfig):
+    """Offline mode: the paper's job — classify a seeded IMDb-shaped
+    dataset monolithically, then in parallel through the orchestrator
+    (``ServerlessFunction`` → ``Engine.classify``). Returns both runs'
+    summaries and their merged predictions."""
+    engine = Engine(build(cfg), RunConfig(), mesh=mesh,
+                    seq_shard=args.seq_shard)
+    params = engine.init_params(args.seed)
+
+    tokens, labels = imdb_reviews(n=args.n_items, seq_len=args.seq_len,
+                                  vocab=cfg.vocab_size, seed=args.seed)
+    store = ArtifactStore()
+    store.put_tree("models/clf", params)
+    job = BatchJob("serve", DatasetRef("imdb", args.n_items, args.seq_len,
+                                       cfg.vocab_size),
+                   "models/clf", args.batch_size)
+    chunks = decompose(job)
+    lat = LatencyModel(cold_start_s=0.2, per_item_s=None)  # real compute
+    injector = FaultInjector(seed=args.seed, crash_prob=args.crash_prob,
+                             straggler_prob=args.straggler_prob)
+
+    def mk(i):
+        return ServerlessFunction(i, store, lat, engine=engine,
+                                  params_ref="models/clf")
+
+    data = {"tokens": tokens}
+    print(f"== job: {args.n_items} items, batch_size={args.batch_size}, "
+          f"{len(chunks)} chunks ==")
+
+    mono = MonolithicRunner(store, MonolithicConfig(),
+                            injector=injector).run(job, chunks, mk,
+                                                   data=data)
+    mono_preds = merge(store, job, chunks)
+    print(f"monolithic: wall={mono.wall_time_s:.1f}s "
+          f"cost=${mono.cost_usd:.6f} chains={mono.n_invocations} "
+          f"crashes={mono.n_crashes}")
+
+    store2 = ArtifactStore()
+    store2.put_tree("models/clf", params)
+    orch = Orchestrator(
+        store2,
+        OrchestratorConfig(max_concurrency=args.concurrency,
+                           retry_max_attempts=6, speculation_factor=3.0),
+        injector=FaultInjector(seed=args.seed + 1,
+                               crash_prob=args.crash_prob,
+                               straggler_prob=args.straggler_prob))
+    par = orch.run(job, chunks,
+                   lambda i: ServerlessFunction(
+                       i, store2, lat, engine=engine,
+                       params_ref="models/clf"), data=data)
+    preds = merge(store2, job, chunks)
+    acc = float((preds == labels).mean())
+    print(f"parallel:   wall={par.wall_time_s:.1f}s "
+          f"cost=${par.cost_usd:.6f} fns={par.n_invocations} "
+          f"retries={par.n_retries} spec={par.n_speculative} "
+          f"crashes={par.n_crashes}")
+    print(f"speedup: {mono.wall_time_s/par.wall_time_s:.1f}x | "
+          f"cost ratio {par.cost_usd/max(mono.cost_usd,1e-12):.2f} | "
+          f"predictions merged exactly-once, acc={acc:.3f} | "
+          f"mono and parallel "
+          f"{'identical' if (mono_preds == preds).all() else 'DIVERGED'}")
+    return {"mono": mono.summary(), "par": par.summary(),
+            "mono_preds": mono_preds, "par_preds": preds}
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="distilbert-imdb")
     ap.add_argument("--n-items", type=int, default=256)
@@ -342,7 +423,8 @@ def main(argv=None):
     ap.add_argument("--horizon", type=float, default=8.0,
                     help="traffic horizon in virtual seconds")
     ap.add_argument("--router-arch", default="qwen2-7b",
-                    help="decoder LM for online generation (smoke-sized)")
+                    help="decoder LM for online generation and the batch "
+                         "DAG (<arch>-smoke for the tiny preset)")
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new-tokens", type=int, default=8)
     ap.add_argument("--n-slots", type=int, default=4)
@@ -411,75 +493,30 @@ def main(argv=None):
                     help="record per-request trace spans (repro.obs "
                          "JSONL) and write them here on shutdown; "
                          "analyze with tools/trace_report.py")
-    args = ap.parse_args(argv)
+    return ap
 
-    mesh = None
-    if args.mesh:
-        shape = tuple(int(x) for x in args.mesh.lower().split("x"))
-        from repro.launch.mesh import make_host_mesh
-        mesh = make_host_mesh(shape, ("data", "model"))
+
+def mesh_from_args(args):
+    """The ("data", "model") mesh ``--mesh DxM`` names, or None."""
+    if not args.mesh:
+        return None
+    from repro.launch.mesh import make_host_mesh
+    shape = tuple(int(x) for x in args.mesh.lower().split("x"))
+    return make_host_mesh(shape, ("data", "model"))
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    compile_cache.enable()
+
+    mesh = mesh_from_args(args)
     if args.http:
-        return run_http(args, mesh)
+        return run_http(args, mesh, configs.get(args.router_arch))
     if args.batch_dag:
-        return run_batch_dag(args)
+        return run_batch_dag(args, configs.get(args.router_arch))
     if args.router or args.calibrate:
-        return run_router(args, mesh)
-    cfg = configs.smoke(args.arch)
-    model = build(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
-    engine = Engine(model, RunConfig(), mesh=mesh, seq_shard=args.seq_shard)
-    params = engine.shard_params(params)
-
-    tokens, labels = imdb_reviews(n=args.n_items, seq_len=args.seq_len,
-                                  vocab=cfg.vocab_size, seed=args.seed)
-    store = ArtifactStore()
-    store.put_tree("models/clf", params)
-    job = BatchJob("serve", DatasetRef("imdb", args.n_items, args.seq_len,
-                                       cfg.vocab_size),
-                   "models/clf", args.batch_size)
-    chunks = decompose(job)
-    lat = LatencyModel(cold_start_s=0.2, per_item_s=None)  # real compute
-    injector = FaultInjector(seed=args.seed, crash_prob=args.crash_prob,
-                             straggler_prob=args.straggler_prob)
-
-    def mk(i):
-        return ServerlessFunction(i, store, lat, engine=engine,
-                                  params_ref="models/clf")
-
-    data = {"tokens": tokens}
-    print(f"== job: {args.n_items} items, batch_size={args.batch_size}, "
-          f"{len(chunks)} chunks ==")
-
-    mono = MonolithicRunner(store, MonolithicConfig(),
-                            injector=injector).run(job, chunks, mk,
-                                                   data=data)
-    print(f"monolithic: wall={mono.wall_time_s:.1f}s "
-          f"cost=${mono.cost_usd:.6f} chains={mono.n_invocations} "
-          f"crashes={mono.n_crashes}")
-
-    store2 = ArtifactStore()
-    store2.put_tree("models/clf", params)
-    orch = Orchestrator(
-        store2,
-        OrchestratorConfig(max_concurrency=args.concurrency,
-                           retry_max_attempts=6, speculation_factor=3.0),
-        injector=FaultInjector(seed=args.seed + 1,
-                               crash_prob=args.crash_prob,
-                               straggler_prob=args.straggler_prob))
-    par = orch.run(job, chunks,
-                   lambda i: ServerlessFunction(
-                       i, store2, lat, engine=engine,
-                       params_ref="models/clf"), data=data)
-    preds = merge(store2, job, chunks)
-    acc = float((preds == labels).mean())
-    print(f"parallel:   wall={par.wall_time_s:.1f}s "
-          f"cost=${par.cost_usd:.6f} fns={par.n_invocations} "
-          f"retries={par.n_retries} spec={par.n_speculative} "
-          f"crashes={par.n_crashes}")
-    print(f"speedup: {mono.wall_time_s/par.wall_time_s:.1f}x | "
-          f"cost ratio {par.cost_usd/max(mono.cost_usd,1e-12):.2f} | "
-          f"predictions merged exactly-once, acc={acc:.3f}")
-    return {"mono": mono.summary(), "par": par.summary()}
+        return run_router(args, mesh, configs.get(args.router_arch))
+    return run_offline(args, mesh, configs.get(args.arch))
 
 
 if __name__ == "__main__":

@@ -10,7 +10,10 @@ Runs a training loop with:
     path end to end (used by tests/examples).
 
 Usage:
-  python -m repro.launch.train --arch qwen2-7b --smoke --steps 200
+  python -m repro.launch.train --arch qwen2-7b-smoke --steps 200
+
+``--arch`` names a published config, or ``<arch>-smoke`` for its
+CPU-sized preset (``repro.configs.get``).
 """
 from __future__ import annotations
 
@@ -34,7 +37,7 @@ class InjectedCrash(RuntimeError):
 
 
 def train_once(args, crash_at: int = -1) -> dict:
-    cfg = configs.smoke(args.arch) if args.smoke else configs.get(args.arch)
+    cfg = configs.get(args.arch)
     model = build(cfg)
     run = RunConfig(remat=args.remat, microbatch=args.microbatch)
     opt = AdamW(schedule=warmup_cosine(args.lr, args.warmup, args.steps))
@@ -86,8 +89,6 @@ def train_once(args, crash_at: int = -1) -> dict:
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-7b")
-    ap.add_argument("--smoke", action="store_true",
-                    help="reduced config (CPU-sized)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=64)

@@ -52,9 +52,15 @@ def _constrain_heads_or_seq(x):
 def attn_specs(cfg: ModelConfig, *, cross: bool = False, d_in: Optional[int] = None):
     d = d_in or cfg.d_model
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    # q and k contract over d_model, not over the second-to-last dim the
+    # default fan-in rule reads (heads): at that scale the initial scores
+    # are d_model/heads times too large and softmax starts one-hot, so a
+    # bf16 rounding flips which key wins
     p = {
-        "wq": AxSpec((d, h, hd), ("d_model", "heads", "head_dim")),
-        "wk": AxSpec((d, kv, hd), ("d_model", "kv_heads", "head_dim")),
+        "wq": AxSpec((d, h, hd), ("d_model", "heads", "head_dim"),
+                     scale=d ** -0.5),
+        "wk": AxSpec((d, kv, hd), ("d_model", "kv_heads", "head_dim"),
+                     scale=d ** -0.5),
         "wv": AxSpec((d, kv, hd), ("d_model", "kv_heads", "head_dim")),
         "wo": AxSpec((h, hd, d), ("heads", "head_dim", "d_model")),
     }
@@ -66,10 +72,10 @@ def attn_specs(cfg: ModelConfig, *, cross: bool = False, d_in: Optional[int] = N
         p["bo"] = AxSpec((d,), ("d_model",), "zeros")
     if cross:
         # cross-attention keys/values come from the encoder stream
-        p["wk"] = AxSpec((cfg.enc_d_model or d, kv, hd),
-                         ("d_model", "kv_heads", "head_dim"))
-        p["wv"] = AxSpec((cfg.enc_d_model or d, kv, hd),
-                         ("d_model", "kv_heads", "head_dim"))
+        de = cfg.enc_d_model or d
+        p["wk"] = AxSpec((de, kv, hd), ("d_model", "kv_heads", "head_dim"),
+                         scale=de ** -0.5)
+        p["wv"] = AxSpec((de, kv, hd), ("d_model", "kv_heads", "head_dim"))
     return p
 
 

@@ -154,7 +154,9 @@ def _embed_in(cfg: ModelConfig, params, tokens=None, embeddings=None,
     if embeddings is not None:
         x = embeddings.astype(jnp.bfloat16)
     else:
-        x = params["embed"].astype(jnp.bfloat16)[tokens]
+        # activations take the embedding's dtype: bf16 when served, fp32
+        # for a float32 reference run over upcast params
+        x = params["embed"][tokens]
     if cfg.emb_scale:
         x = x * jnp.sqrt(float(cfg.d_model)).astype(x.dtype)
     if cfg.pos == "learned":

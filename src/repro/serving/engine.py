@@ -143,11 +143,22 @@ class Engine:
         return dataclasses.replace(self, mesh=mesh)
 
     def shard_params(self, params):
-        """Place ``params`` in the planner layout (no-op without a mesh)."""
+        """Place ``params`` on the device(s): the planner layout under a
+        mesh, the default device without one. Host (numpy) leaves are
+        uploaded here, once, so no later call re-sends them."""
         if self.mesh is None:
-            return params
+            return jax.device_put(params)
         with self._ctx():
             return jax.device_put(params, self.params_sharding)
+
+    def init_params(self, seed: int):
+        """Random params made from ``seed`` directly on the device(s), in
+        the layout :meth:`shard_params` gives: under a mesh each device
+        materializes only its own shard, so a model larger than one
+        device's memory never passes through one device or the host."""
+        init = jax.jit(self.model.init, out_shardings=self.params_sharding)
+        with self._ctx():
+            return init(jax.random.PRNGKey(seed))
 
     def shard_inputs(self, batch):
         """Batch-shard input leaves over the data axes (dim 0)."""

@@ -12,7 +12,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.dist import compat
 from repro.dist.collectives import (compress_psum, seq_sharded_decode,
                                     seq_sharded_write_decode)
 from repro.kernels.decode_attention.ref import decode_attention_ref
@@ -84,9 +83,9 @@ def test_seq_sharded_decode_matches_reference_without_mesh():
 def _one_device_psum(x, method):
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("pod",))
     from jax.sharding import PartitionSpec as P
-    f = compat.shard_map(lambda v: compress_psum(v, "pod", method),
-                         mesh=mesh, in_specs=P(), out_specs=P(),
-                         check_vma=False)
+    f = jax.shard_map(lambda v: compress_psum(v, "pod", method),
+                      mesh=mesh, in_specs=P(), out_specs=P(),
+                      axis_names={"pod"}, check_vma=False)
     return jax.jit(f)(x)
 
 

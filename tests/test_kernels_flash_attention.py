@@ -20,6 +20,8 @@ CASES = [
     (1, 100, 100, 8, 2, 64, True, None, None),   # non-multiple: pad path
     (1, 96, 200, 2, 2, 128, False, None, 30.0),  # pad + bidir + cap
     (1, 128, 128, 4, 2, 192, True, None, None),  # nemotron head_dim
+    (1, 128, 128, 7, 1, 128, True, None, None),  # qwen2 GQA, lane slabs
+    (1, 64, 64, 4, 2, 256, True, 16, None),      # two-vreg-wide slabs
 ]
 
 
