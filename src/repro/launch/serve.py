@@ -56,6 +56,7 @@ Weights are random, made from ``--seed`` on the device(s).
 from __future__ import annotations
 
 import argparse
+import time
 
 import jax
 
@@ -301,10 +302,11 @@ def run_http(args, mesh, cfg: ModelConfig, until=None):
             await door.close()
             print(router.report().format_line())
             if args.trace:
+                t_dump = time.perf_counter()
                 n = obs.tracer.dump(args.trace)
-                print(f"== trace: {n} events -> {args.trace} "
-                      f"(analyze: python tools/trace_report.py "
-                      f"{args.trace}) ==")
+                print(f"== trace: {n} events -> {args.trace} in "
+                      f"{time.perf_counter() - t_dump:.3f} s (analyze: "
+                      f"python tools/trace_report.py {args.trace}) ==")
 
     out = {"port": door.port}
     try:

@@ -22,14 +22,14 @@ from typing import Optional
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry,
                        DEFAULT_BUCKETS, log_buckets)
 from .trace import (TraceRecorder, SPAN_EVENTS, TERMINAL_EVENTS,
-                    load_jsonl, spans_of)
+                    DELIVERY_EVENTS, load_jsonl, span, spans_of)
 from .promlint import lint_prometheus
 
 __all__ = [
     "Observability", "MetricsRegistry", "Counter", "Gauge", "Histogram",
     "TraceRecorder", "DEFAULT_BUCKETS", "log_buckets",
     "lint_prometheus", "SPAN_EVENTS", "TERMINAL_EVENTS",
-    "load_jsonl", "spans_of",
+    "DELIVERY_EVENTS", "load_jsonl", "span", "spans_of",
 ]
 
 OUTCOMES = ("completed", "cancelled", "expired", "rejected")

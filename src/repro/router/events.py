@@ -92,15 +92,16 @@ class VirtualClock:
 class WallClock:
     """Real time, in seconds since construction (monotonic). The event
     loop's serving clock: arrivals, first tokens, and billing all read
-    the same origin, so TTFT/TPOT are MEASURED, not modeled."""
+    the same origin, so TTFT/TPOT are MEASURED, not modeled. ``origin``
+    is that zero in ``time.monotonic()`` seconds."""
 
     virtual = False
 
     def __init__(self):
-        self._t0 = time.monotonic()
+        self.origin = time.monotonic()
 
     def now(self) -> float:
-        return time.monotonic() - self._t0
+        return time.monotonic() - self.origin
 
     def advance_to(self, t: float) -> None:
         pass                      # wall time advances itself
@@ -282,6 +283,10 @@ class RouterCore:
         self.obs = obs
         if getattr(self.pool, "obs", None) is None:
             self.pool.obs = obs
+        if not self._clock.virtual:
+            # the wall clock's zero on the host's monotonic scale, so
+            # client-side stamps can be read against the trace's times
+            obs.trace("clock", 0.0, monotonic=self._clock.origin)
         return obs
 
     # -- the clock -------------------------------------------------------
@@ -538,7 +543,6 @@ class RouterCore:
         # first tokens are stamped at their PREFILL event (mid-round),
         # exactly once — not at the round boundary
         timed = []
-        decode_rids: List[int] = []
         for ev in log.events:
             t_ev = t0 + self._event_offset(ev, log, round_s)
             if ev.prefill:
@@ -549,8 +553,6 @@ class RouterCore:
                     if stamped:
                         obs.m_ttft.observe(t_ev - ev.req.arrival_t)
                         obs.trace("first_token", t_ev, rid=ev.req.rid)
-            elif obs is not None and ev.req.rid not in decode_rids:
-                decode_rids.append(ev.req.rid)
             timed.append((ev.req, ev.tok, t_ev, ev.prefill))
         produced = (sum(len(q.generated) for q in r.inflight())
                     + sum(len(q.generated) for q in done_now)
@@ -564,12 +566,8 @@ class RouterCore:
                 if record_first_token(q, t_visible) and obs is not None:
                     obs.m_ttft.observe(t_visible - q.arrival_t)
                     obs.trace("first_token", t_visible, rid=q.rid)
-        if obs is not None:
-            if produced:
-                obs.m_tokens.inc(produced)
-            for rid in decode_rids:
-                obs.trace("decode_round", t_visible, rid=rid,
-                          replica=r.replica_id)
+        if obs is not None and produced:
+            obs.m_tokens.inc(produced)
         for q in done_now:
             q.finish_t = t_visible
             self.completed.append(q)

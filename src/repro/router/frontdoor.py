@@ -35,6 +35,11 @@ streaming NDJSON token events over chunked transfer encoding.
   * ``GET /metrics.json`` — the legacy JSON counter blob, now served
     O(1) from live state + registry histograms (``live_stats``).
 
+With a tracer attached (``Observability(tracer=...)``) the front door
+records one ``sent`` delivery event per token chunk once it is handed
+to the client's socket (docs/OBSERVABILITY.md), and the serve loop's
+turn given to the handlers is the host span ``repro:frontdoor``.
+
 A mid-flight client disconnect cancels its request —
 ``EventRouter.cancel`` frees the slot's cache row via
 ``ContinuousBatcher.cancel`` between rounds, so the round (and every
@@ -54,7 +59,7 @@ from typing import Any, Dict, Optional, Tuple
 import numpy as np
 
 from repro.core.cost_model import AWSPriceBook, TPUPriceBook
-from repro.obs import Observability
+from repro.obs import Observability, span
 from repro.router.events import (ARRIVAL, EventQueue, RouterConfig,
                                  RouterCore, VirtualClock)
 from repro.router.metrics import RouterReport
@@ -199,7 +204,8 @@ class EventRouter(RouterCore):
                 if durations:
                     self.pool.retire_drained(self.clock)
                     # let the HTTP handlers flush this round's tokens
-                    await asyncio.sleep(0)
+                    with span("frontdoor"):
+                        await asyncio.sleep(0)
                     continue
                 if self._stopping and not self._intake and self._drained():
                     break
@@ -393,6 +399,7 @@ class HttpFrontDoor:
         # the request body is fully read, so any further read resolving
         # means the client went away (EOF / reset) -> cancel mid-flight
         watchdog = asyncio.ensure_future(reader.read(1))
+        tracer = self.obs.tracer
         try:
             while True:
                 getter = asyncio.ensure_future(stream.get())
@@ -404,6 +411,11 @@ class HttpFrontDoor:
                         break
                     self._chunk(writer, item)
                     await writer.drain()
+                    if tracer is not None:
+                        # delivery, on the router's clock: the token left
+                        # the server now; it was committed at item["t"]
+                        self.obs.trace("sent", self.router.clock,
+                                       rid=req.rid, committed=item["t"])
                 else:                      # client disconnected
                     getter.cancel()
                     self.obs.m_http_disconnects.inc()

@@ -31,6 +31,7 @@ online router (``repro.router`` — each pool replica wraps one
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any, Dict, List, Optional, Sequence
@@ -39,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import span
 from repro.serving.engine import Engine
 from repro.serving.paged import PageAllocator, PagesExhausted
 from repro.serving.sampler import sample
@@ -46,6 +48,9 @@ from repro.serving.sampler import sample
 # The BENCH_8 time-attribution taxonomy (benchmarks/profiling.py uses
 # the same names): where a scheduling round's wall time goes.
 BUCKETS = ("prefill", "decode_attention", "sampler", "host_scheduler")
+# the host span (``repro:<name>``, repro.obs.trace.span) that covers the
+# same stretch as each dispatch bucket
+_SPANS = {"prefill": "prefill", "decode_attention": "decode"}
 
 
 @dataclasses.dataclass
@@ -311,6 +316,20 @@ class ContinuousBatcher:
         self._bucket_s["sampler"] += time.perf_counter() - t0
         return out
 
+    @contextlib.contextmanager
+    def _timed(self, bucket: str):
+        """One dispatch's stretch of the round: its host span on the
+        profiler's clock and its wall-time bucket, opened and closed at
+        the same two points so the two cannot disagree. Under fused
+        sampling it runs until the token is on the host; without, the
+        host sampler that brings it there is the ``sampler`` bucket."""
+        t0 = time.perf_counter()
+        with span(_SPANS[bucket]):
+            try:
+                yield
+            finally:
+                self._bucket_s[bucket] += time.perf_counter() - t0
+
     def _fused_kw(self) -> dict:
         return dict(temperature=self.temperature, top_k=self.top_k,
                     top_p=self.top_p)
@@ -324,13 +343,14 @@ class ContinuousBatcher:
         """
         t0 = time.perf_counter()
         attributed0 = sum(self._bucket_s.values())
-        admitted = self.scheduler.admit()
-        if self.paged:
-            self._step_paged(admitted)
-        elif self.batched:
-            self._step_batched(admitted)
-        else:
-            self._step_per_slot(admitted)
+        with span("round"):
+            admitted = self.scheduler.admit()
+            if self.paged:
+                self._step_paged(admitted)
+            elif self.batched:
+                self._step_batched(admitted)
+            else:
+                self._step_per_slot(admitted)
         self.rounds += 1
         attributed = sum(self._bucket_s.values()) - attributed0
         self._bucket_s["host_scheduler"] += max(
@@ -362,43 +382,46 @@ class ContinuousBatcher:
                 self._reject(slot)
                 continue
             key = self._next_key()
-            t_pf = time.perf_counter()
-            if self.fused_sampling:
-                toks, self.cache = self.engine.prefill_into_sample(
-                    self.params, self.cache, slot, req.prompt[None], key,
-                    max_len=self.max_len, **self._fused_kw())
-                tok = int(toks[0])
-                self._bucket_s["prefill"] += time.perf_counter() - t_pf
-            else:
-                logits, self.cache = self.engine.prefill_into(
-                    self.params, self.cache, slot, req.prompt[None],
-                    max_len=self.max_len)
-                self._bucket_s["prefill"] += time.perf_counter() - t_pf
+            with self._timed("prefill"):
+                if self.fused_sampling:
+                    toks, self.cache = self.engine.prefill_into_sample(
+                        self.params, self.cache, slot, req.prompt[None],
+                        key, max_len=self.max_len, **self._fused_kw())
+                    tok = int(toks[0])
+                else:
+                    logits, self.cache = self.engine.prefill_into(
+                        self.params, self.cache, slot, req.prompt[None],
+                        max_len=self.max_len)
+            if not self.fused_sampling:
                 tok = int(self._sample_host(logits, key)[0])
             self._tokens[slot, 0] = tok
             self._commit_batched(slot, tok, prefill=True)
         if not self.scheduler.active:
             return
+        toks = self._decode_all()
+        for slot in list(self.scheduler.active):
+            self._commit_batched(slot, int(toks[slot]))
+
+    def _decode_all(self) -> np.ndarray:
+        """The round's one ragged decode dispatch over every row of the
+        shared cache (dense or paged); returns each row's next token on
+        the host."""
         key = self._next_key()
-        t_dec = time.perf_counter()
-        if self.fused_sampling:
-            toks, self.cache = self.engine.decode_sample(
-                self.params, self.cache, self._tokens, key,
-                **self._fused_kw())
-            toks = np.asarray(toks, np.int32)
-            self._bucket_s["decode_attention"] += (
-                time.perf_counter() - t_dec)
-        else:
-            logits, self.cache = self.engine.decode(self.params, self.cache,
-                                                    self._tokens)
-            self._bucket_s["decode_attention"] += (
-                time.perf_counter() - t_dec)
+        with self._timed("decode_attention"):
+            if self.fused_sampling:
+                toks, self.cache = self.engine.decode_sample(
+                    self.params, self.cache, self._tokens, key,
+                    **self._fused_kw())
+                toks = np.asarray(toks, np.int32)
+            else:
+                logits, self.cache = self.engine.decode(
+                    self.params, self.cache, self._tokens)
+        if not self.fused_sampling:
             toks = self._sample_host(logits, key)
         self.decode_dispatches += 1
         self.decode_steps += len(self.scheduler.active)
         self._tokens[:, 0] = toks
-        for slot in list(self.scheduler.active):
-            self._commit_batched(slot, int(toks[slot]))
+        return toks
 
     def _commit_batched(self, slot: int, tok: int, prefill: bool = False):
         req = self.scheduler.slots[slot]
@@ -451,20 +474,19 @@ class ContinuousBatcher:
                 else:
                     self._reject(slot)  # no active row will ever free
                 continue
-            t_pf = time.perf_counter()
-            self.cache = self.engine.assign_row_pages(
-                self.cache, slot, plan.pages, plan.start_len)
             key = self._next_key()
-            if self.fused_sampling:
-                toks, self.cache = self.engine.extend_row_sample(
-                    self.params, self.cache, slot, plan.suffix[None], key,
-                    **self._fused_kw())
-                tok = int(toks[0])
-                self._bucket_s["prefill"] += time.perf_counter() - t_pf
-            else:
-                logits, self.cache = self.engine.extend_row(
-                    self.params, self.cache, slot, plan.suffix[None])
-                self._bucket_s["prefill"] += time.perf_counter() - t_pf
+            with self._timed("prefill"):
+                self.cache = self.engine.assign_row_pages(
+                    self.cache, slot, plan.pages, plan.start_len)
+                if self.fused_sampling:
+                    toks, self.cache = self.engine.extend_row_sample(
+                        self.params, self.cache, slot, plan.suffix[None],
+                        key, **self._fused_kw())
+                    tok = int(toks[0])
+                else:
+                    logits, self.cache = self.engine.extend_row(
+                        self.params, self.cache, slot, plan.suffix[None])
+            if not self.fused_sampling:
                 tok = int(self._sample_host(logits, key)[0])
             self._host_len[slot] = len(req.prompt)
             self._tokens[slot, 0] = tok
@@ -482,24 +504,7 @@ class ContinuousBatcher:
                 self.cache = self.engine.assign_row_pages(
                     self.cache, slot, self.allocator.rows[slot],
                     self._host_len[slot])
-        key = self._next_key()
-        t_dec = time.perf_counter()
-        if self.fused_sampling:
-            toks, self.cache = self.engine.decode_sample(
-                self.params, self.cache, self._tokens, key,
-                **self._fused_kw())
-            toks = np.asarray(toks, np.int32)
-            self._bucket_s["decode_attention"] += (
-                time.perf_counter() - t_dec)
-        else:
-            logits, self.cache = self.engine.decode(self.params, self.cache,
-                                                    self._tokens)
-            self._bucket_s["decode_attention"] += (
-                time.perf_counter() - t_dec)
-            toks = self._sample_host(logits, key)
-        self.decode_dispatches += 1
-        self.decode_steps += len(self.scheduler.active)
-        self._tokens[:, 0] = toks
+        toks = self._decode_all()
         for slot in list(self.scheduler.active):
             self._host_len[slot] += 1
             self._commit_paged(slot, int(toks[slot]))
@@ -518,34 +523,33 @@ class ContinuousBatcher:
     def _step_per_slot(self, admitted: List[int]):
         for slot in admitted:
             req = self.scheduler.slots[slot]
-            t_pf = time.perf_counter()
-            logits, cache = self.engine.prefill(self.params,
-                                                req.prompt[None])
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-            self._bucket_s["prefill"] += time.perf_counter() - t_pf
+            with self._timed("prefill"):
+                logits, cache = self.engine.prefill(self.params,
+                                                    req.prompt[None])
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+                first = int(tok[0, 0])
             self.caches[slot] = cache
             self._last_tok[slot] = tok
-            self._commit_per_slot(slot, tok, prefill=True)
+            self._commit_per_slot(slot, first, prefill=True)
         for slot in list(self.scheduler.active):
-            t_dec = time.perf_counter()
-            logits, cache = self.engine.decode(
-                self.params, self.caches[slot], self._last_tok[slot])
-            self._bucket_s["decode_attention"] += (
-                time.perf_counter() - t_dec)
+            with self._timed("decode_attention"):
+                logits, cache = self.engine.decode(
+                    self.params, self.caches[slot], self._last_tok[slot])
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+                nxt = int(tok[0, 0])
             self.decode_dispatches += 1
             self.decode_steps += 1
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
             self.caches[slot] = cache
             self._last_tok[slot] = tok
-            self._commit_per_slot(slot, tok)
+            self._commit_per_slot(slot, nxt)
 
-    def _commit_per_slot(self, slot: int, tok, prefill: bool = False):
+    def _commit_per_slot(self, slot: int, tok: int, prefill: bool = False):
         req = self.scheduler.slots[slot]
-        self.scheduler.step_done(slot, int(tok[0, 0]))
+        self.scheduler.step_done(slot, tok)
         if self.scheduler.slots[slot] is None:  # completed -> evict
             self.caches.pop(slot, None)
             self._last_tok.pop(slot, None)
-        self._fire_on_token(req, int(tok[0, 0]), prefill)
+        self._fire_on_token(req, tok, prefill)
 
     # -- mid-flight cancellation (client disconnect) --------------------
 

@@ -41,6 +41,7 @@ from repro.kernels.decode_attention.fused_sampling import fused_sample
 from repro.kernels.decode_attention.quant import KV_DTYPES
 from repro.models.common import RunConfig
 from repro.models.model_zoo import Model
+from repro.obs.trace import span
 from repro.serving.sampler import sample
 
 
@@ -244,10 +245,13 @@ class Engine:
 
         Under a mesh, ``params`` may arrive in any layout (use
         ``shard_params`` once to place them); tokens are batch-sharded
-        here and the logits come back batch-sharded.
+        here and the logits come back batch-sharded. The host span
+        ``repro:classify`` covers the call, tokens in to labels on the
+        host.
         """
-        return np.asarray(jnp.argmax(self.classify_logits(params, tokens),
-                                     axis=-1))
+        with span("classify"):
+            return np.asarray(jnp.argmax(
+                self.classify_logits(params, tokens), axis=-1))
 
     def classify_logits(self, params, tokens) -> np.ndarray:
         with self._ctx():

@@ -40,6 +40,7 @@ from repro.router import (ArrivalQueue, EventQueue, EventRouter,
                           QueueDepthPolicy, ReplicaConfig, ReplicaPool,
                           Router, VirtualClock, WallClock, bursty_arrivals,
                           diurnal_arrivals, make_requests, poisson_arrivals)
+from repro.obs import Observability, TraceRecorder
 from repro.router.metrics import record_first_token
 from repro.serving import Engine, Request
 
@@ -377,11 +378,11 @@ async def _get(port, path):
     return status, json.loads(body)
 
 
-def _door(engine, params, **pool_kw):
+def _door(engine, params, obs=None, **pool_kw):
     router = EventRouter(_pool(engine, params, lat=WALL_LAT, n_slots=4,
                                **pool_kw),
                          QueueDepthPolicy(max_replicas=2),
-                         clock=WallClock(), traffic_name="http")
+                         clock=WallClock(), traffic_name="http", obs=obs)
     return router, HttpFrontDoor(router, port=0)
 
 
@@ -470,3 +471,68 @@ def test_http_capacity_reject_ends_stream_cleanly(stack):
     assert end["event"] == "end" and not end["done"]
     assert end["n_tokens"] == 0 and end["ttft_s"] is None
     assert router.report().n_rejected == 1
+
+
+def _streamed(engine, params, obs):
+    """Four concurrent clients through a front door built with ``obs``;
+    returns the router and each client's chunks."""
+    async def main():
+        router, door = _door(engine, params, obs=obs)
+        await door.start()
+        streams = await asyncio.gather(
+            *(_generate(door.port, i, n_new=3 + i) for i in range(4)))
+        await door.close()
+        return router, streams
+
+    return asyncio.run(main())
+
+
+def test_http_traced_clock_first_then_one_sent_per_streamed_token(stack):
+    """With a tracer the trace opens with the wall clock's origin on the
+    host's monotonic scale, and the front door records exactly one
+    ``sent`` per token chunk it streamed, after its commit, under the
+    stream's rid. Delivery events stay out of the lifecycle spans."""
+    engine, params, _ = stack
+    obs = Observability(tracer=TraceRecorder())
+    router, streams = _streamed(engine, params, obs)
+    events = obs.tracer.events
+    assert events[0] == {"t": 0.0, "event": "clock",
+                         "monotonic": router._clock.origin}
+    assert sum(e["event"] == "clock" for e in events) == 1
+    sent = [e for e in events if e["event"] == "sent"]
+    for chunks in streams:
+        toks, end = chunks[:-1], chunks[-1]
+        mine = [e for e in sent if e["rid"] == end["rid"]]
+        assert len(mine) == len(toks) == end["n_tokens"]
+        assert [e["committed"] for e in mine] == [c["t"] for c in toks]
+        assert all(e["committed"] <= e["t"] for e in mine)
+    assert len(sent) == sum(len(c) - 1 for c in streams)
+    assert all(e["event"] != "sent"
+               for span in obs.tracer.spans().values() for e in span)
+
+
+def test_http_untraced_records_nothing_and_streams_same_tokens(stack,
+                                                               monkeypatch):
+    """Without a tracer the front door records nothing (not even a call
+    into the tracing helper per chunk) and the clients receive the same
+    tokens as a traced run."""
+    engine, params, _ = stack
+    calls = []
+    real = Observability.trace
+
+    def spy(self, event, *a, **kw):
+        calls.append(event)
+        return real(self, event, *a, **kw)
+
+    monkeypatch.setattr(Observability, "trace", spy)
+    router, plain = _streamed(engine, params, None)
+    assert router.obs.tracer is None
+    assert "sent" not in calls
+    traced_obs = Observability(tracer=TraceRecorder())
+    _, traced = _streamed(engine, params, traced_obs)
+    assert "sent" in calls and len(traced_obs.tracer) > 0
+
+    def tokens(streams):
+        return [[c["token"] for c in chunks[:-1]] for chunks in streams]
+    assert tokens(plain) == tokens(traced)
+

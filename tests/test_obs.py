@@ -220,13 +220,19 @@ def test_trace_report_tool_renders_waterfall_and_buckets(tmp_path):
              buckets={"prefill": 0.05, "decode_attention": 0.08,
                       "sampler": 0.01, "host_scheduler": 0.02})
     rec.emit("first_token", 0.35, rid=0)
-    rec.emit("decode_round", 0.5, rid=0, replica=0)
+    rec.emit("round", 0.5, replica=0, round_s=0.0, n_active=1,
+             crashed=False, rids=[0])
     rec.emit("finish", 0.5, rid=0, n_tokens=2)
+    rec.emit("sent", 0.6, rid=0, committed=0.5)   # delivery after finish
     path = tmp_path / "t.jsonl"
     rec.dump(str(path))
 
     tr = _load_tool("trace_report")
-    text = tr.report(tr.load(str(path)))
+    events = tr.load(str(path))
+    assert tr.rounds_of(events) == {0: 2}       # from the rounds' rids
+    assert [e["event"] for e in tr.spans_of(events)[0]] == [
+        "queued", "admitted", "first_token", "finish"]
+    text = tr.report(events)
     assert "waterfall" in text and "finish" in text
     for b in ("prefill", "decode_attention", "sampler", "host_scheduler"):
         assert b in text

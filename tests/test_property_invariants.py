@@ -468,8 +468,8 @@ def test_paged_kernel_parity_random_lengths(seed, lens):
 
 import functools  # noqa: E402
 
-from repro.obs import (Histogram, Observability, TERMINAL_EVENTS,  # noqa: E402
-                       TraceRecorder, log_buckets)
+from repro.obs import (Histogram, Observability, SPAN_EVENTS,  # noqa: E402
+                       TERMINAL_EVENTS, TraceRecorder, log_buckets)
 
 
 @given(vals=st.lists(st.floats(1e-6, 1e3, allow_nan=False), max_size=200),
@@ -515,15 +515,17 @@ def test_trace_spans_monotone_with_single_terminal(data):
         rid = data.draw(st.integers(0, n - 1))
         t += data.draw(st.sampled_from([0.0, 0.1, 0.5]))
         if stage[rid] >= len(LIFE):
-            rec.emit("round", t, replica=0)          # system noise
+            # delivery may follow finish; it rides beside the span
+            rec.emit("sent", t, rid=rid, committed=t)
             continue
         ev = LIFE[stage[rid]]
         if ev == "first_token" and data.draw(st.booleans()):
-            rec.emit("decode_round", t, rid=rid)     # extra rounds ok
+            rec.emit("round", t, replica=0, rids=[rid])  # extra rounds ok
             continue
         rec.emit(ev, t, rid=rid)
         stage[rid] += 1
     for rid, span in rec.spans().items():
+        assert all(e["event"] in SPAN_EVENTS for e in span)
         ts = [e["t"] for e in span]
         assert ts == sorted(ts)                      # monotone per span
         terms = [e for e in span if e["event"] in TERMINAL_EVENTS]

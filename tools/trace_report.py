@@ -6,7 +6,8 @@ object per line; ``launch/serve.py --trace FILE`` and
 
   * **per-request waterfall** — one row per rid, columns for the span
     timestamps (queued / admitted / first_token / terminal) plus
-    derived TTFT, total latency, decode-round count, and outcome; an
+    derived TTFT, total latency, the number of rounds the request was
+    in flight (the ``rids`` of the ``round`` events), and outcome; an
     ASCII timeline bar shows queue-wait vs. in-flight time on a shared
     time axis.
   * **per-round time attribution** — the BENCH_8 bucket taxonomy
@@ -27,6 +28,7 @@ from typing import Dict, List
 # Keep in sync with benchmarks/profiling.py BUCKETS (BENCH_8 taxonomy).
 BUCKETS = ("prefill", "decode_attention", "sampler", "host_scheduler")
 TERMINALS = ("finish", "cancel", "expire", "reject")
+DELIVERY = ("sent",)      # may follow the terminal event; not a span
 
 
 def load(path: str) -> List[dict]:
@@ -37,8 +39,18 @@ def load(path: str) -> List[dict]:
 def spans_of(events: List[dict]) -> Dict[int, List[dict]]:
     out: Dict[int, List[dict]] = {}
     for e in events:
-        if "rid" in e:
+        if "rid" in e and e["event"] not in DELIVERY:
             out.setdefault(e["rid"], []).append(e)
+    return out
+
+
+def rounds_of(events: List[dict]) -> Dict[int, int]:
+    """Rounds each request was in flight for, from the ``round`` events."""
+    out: Dict[int, int] = {}
+    for e in events:
+        if e["event"] == "round":
+            for rid in e.get("rids", ()):
+                out[rid] = out.get(rid, 0) + 1
     return out
 
 
@@ -61,6 +73,7 @@ def waterfall(events: List[dict], width: int = 48,
     t0 = min(e["t"] for e in events)
     t1 = max(e["t"] for e in events)
     scale = (width - 1) / max(t1 - t0, 1e-9)
+    n_rounds_of = rounds_of(events)
 
     lines = [
         f"{'rid':>5} {'queued':>7} {'admit':>7} {'first':>7} "
@@ -79,7 +92,7 @@ def waterfall(events: List[dict], width: int = 48,
                          if e["event"] in TERMINALS), None)
         te = terminal["t"] if terminal else None
         outcome = terminal["event"] if terminal else "open"
-        n_rounds = sum(1 for e in span if e["event"] == "decode_round")
+        n_rounds = n_rounds_of.get(rid, 0)
         ttft = (tf - tq) if (tf is not None and tq is not None) else None
         total = (te - tq) if (te is not None and tq is not None) else None
 
