@@ -124,6 +124,10 @@ class Observability:
         self.m_http_disconnects = r.counter(
             "repro_http_disconnects_total",
             "Client disconnects that cancelled an in-flight request.")
+        self.m_http_backpressure_waits = r.counter(
+            "repro_http_backpressure_waits_total",
+            "Times a stream handler awaited its socket's drain because "
+            "tokens handed over on commit outran the client.")
 
         # -- run-level --
         self.m_clock_s = r.gauge(

@@ -585,7 +585,8 @@ class RouterCore:
                     ) -> None:
         """Streaming hook: every token the round committed, with its
         event timestamp, in commit order. No-op here; the event-driven
-        front door forwards them to per-request subscriber queues."""
+        front door hands each to its request's sink, which writes it to
+        the client's socket before the round returns."""
 
     def _step_all(self) -> List[float]:
         """Step every replica that has work — draining replicas keep

@@ -12,16 +12,24 @@ synchronous-round ``Router`` is the first). Two modes, one core:
     path below reuse the same policies, ``FaultInjector`` crashes, and
     metrics with confidence.
   * ``serve()`` — WALL clock, asyncio. Live callers ``submit()``
-    requests (no traffic generator) and read their tokens back from a
-    per-request stream as rounds commit them; TTFT/TPOT come from REAL
-    timestamps at first-token/per-token events. Between rounds the
-    loop yields to the event loop so the HTTP handlers flush streams;
-    when idle it sleeps on a wake event (new submission) or the next
+    requests (no traffic generator) with a sink that the round which
+    commits a token calls with it, then once with the stream's end;
+    TTFT/TPOT come from REAL timestamps at first-token/per-token
+    events. Between rounds the loop yields one turn to the event loop
+    so the HTTP handlers accept connections and read requests; when
+    idle it sleeps on a wake event (new submission) or the next
     cold-start deadline.
 
 ``HttpFrontDoor`` is the thin serving layer on top: a stdlib-only
 HTTP/1.1 server (``asyncio.start_server`` — no extra dependencies)
-streaming NDJSON token events over chunked transfer encoding.
+streaming NDJSON token events over chunked transfer encoding. Each
+token is written into the client's transport in the round that commits
+it (``writer.write`` is synchronous and buffered), with no task or
+loop turn between commit and socket. A transport whose buffer is above
+its high-water mark keeps that stream's later tokens in order in a
+per-request backlog, and the handler awaits ``writer.drain()`` before
+writing them (``repro_http_backpressure_waits_total``); the rounds and
+every other stream go on meanwhile.
 
   * ``POST /v1/generate``   body ``{"prompt": [ints], "max_new_tokens":
     n, "priority": p, "deadline_s": s}`` → one chunk per token
@@ -36,8 +44,9 @@ streaming NDJSON token events over chunked transfer encoding.
     O(1) from live state + registry histograms (``live_stats``).
 
 With a tracer attached (``Observability(tracer=...)``) the front door
-records one ``sent`` delivery event per token chunk once it is handed
-to the client's socket (docs/OBSERVABILITY.md), and the serve loop's
+records one ``sent`` delivery event per token chunk at the write that
+hands it to the client's socket, in the commit round unless the
+stream was backed up (docs/OBSERVABILITY.md), and the serve loop's
 turn given to the handlers is the host span ``repro:frontdoor``.
 
 A mid-flight client disconnect cancels its request —
@@ -54,7 +63,7 @@ from __future__ import annotations
 import asyncio
 import json
 from collections import deque
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import numpy as np
 
@@ -67,6 +76,12 @@ from repro.router.policy import AutoscalePolicy
 from repro.router.pool import ReplicaPool
 from repro.router.queue import QueueConfig
 from repro.serving.batching import Request
+
+
+# A live request's delivery: called with the request and each committed
+# token's item, then once with its ``{"event": "end"}`` item
+# (``EventRouter.submit``).
+Sink = Callable[[Request, Dict[str, Any]], None]
 
 
 class EventRouter(RouterCore):
@@ -85,7 +100,8 @@ class EventRouter(RouterCore):
                          traffic_name, clock=clock or VirtualClock(),
                          obs=obs)
         self._intake: deque = deque()        # live submissions, pre-queue
-        self._streams: Dict[int, asyncio.Queue] = {}   # id(req) -> stream
+        # id(req) -> (req, sink): where each live request's items go
+        self._streams: Dict[int, Tuple[Request, Sink]] = {}
         self._rid_seq = len(traffic)
         self._wake: Optional[asyncio.Event] = None
         self._stopping = False
@@ -128,12 +144,13 @@ class EventRouter(RouterCore):
 
     # -- live wall-clock mode --------------------------------------------
 
-    def submit(self, prompt, max_new_tokens: int, *, priority: int = 0,
-               deadline_s: Optional[float] = None
-               ) -> Tuple[Request, asyncio.Queue]:
-        """Live intake: returns the request and its token stream — one
-        ``{"token", "t", "prefill", "done"}`` item per committed token,
-        then a ``None`` sentinel (completion, cancellation, expiry, or
+    def submit(self, prompt, max_new_tokens: int, sink: Sink, *,
+               priority: int = 0, deadline_s: Optional[float] = None
+               ) -> Request:
+        """Live intake: returns the request. ``sink`` is called with it
+        and one ``{"token", "t", "prefill", "done"}`` item per token,
+        inside the round that commits the token, then once with the
+        ``{"event": "end"}`` item (completion, cancellation, expiry, or
         rejection)."""
         req = Request(rid=self._rid_seq,
                       prompt=np.asarray(prompt, np.int32),
@@ -141,8 +158,7 @@ class EventRouter(RouterCore):
                       arrival_t=self.clock, deadline_s=deadline_s,
                       priority=int(priority))
         self._rid_seq += 1
-        stream: asyncio.Queue = asyncio.Queue()
-        self._streams[id(req)] = stream
+        self._streams[id(req)] = (req, sink)
         self._intake.append(req)
         # fold live requests into the avg-token estimator the trace
         # modes precompute from the full trace
@@ -151,7 +167,7 @@ class EventRouter(RouterCore):
         self._req_count += 1
         if self._wake is not None:
             self._wake.set()
-        return req, stream
+        return req
 
     def cancel(self, req: Request) -> bool:
         """Client went away: remove ``req`` wherever it is — intake,
@@ -203,7 +219,8 @@ class EventRouter(RouterCore):
                 durations = self._step_all()
                 if durations:
                     self.pool.retire_drained(self.clock)
-                    # let the HTTP handlers flush this round's tokens
+                    # the round wrote its tokens to the sockets; give
+                    # the handlers one turn to accept and read requests
                     with span("frontdoor"):
                         await asyncio.sleep(0)
                     continue
@@ -222,8 +239,8 @@ class EventRouter(RouterCore):
         finally:
             self.pool.retire_all(self.clock)
             self._close_terminal_streams()
-            for req_id in list(self._streams):
-                self._streams.pop(req_id).put_nowait(None)
+            for req, _ in list(self._streams.values()):
+                self._close_stream(req)
 
     def report(self) -> RouterReport:
         """Accounting so far (wall mode: call after ``serve`` returns
@@ -276,19 +293,26 @@ class EventRouter(RouterCore):
         for i, (req, _tok, _t, _prefill) in enumerate(timed):
             last[id(req)] = i
         for i, (req, tok, t, prefill) in enumerate(timed):
-            stream = self._streams.get(id(req))
-            if stream is None:
+            entry = self._streams.get(id(req))
+            if entry is None:
                 continue
             done = req.done and last[id(req)] == i
-            stream.put_nowait({"token": tok, "t": t,
-                               "prefill": prefill, "done": done})
+            entry[1](req, {"token": tok, "t": t, "prefill": prefill,
+                           "done": done})
             if done:
                 self._close_stream(req)
 
     def _close_stream(self, req: Request) -> None:
-        stream = self._streams.pop(id(req), None)
-        if stream is not None:
-            stream.put_nowait(None)
+        entry = self._streams.pop(id(req), None)
+        if entry is not None:
+            entry[1](req, {
+                "event": "end", "rid": req.rid,
+                "n_tokens": len(req.generated), "done": req.done,
+                "ttft_s": (None if req.first_token_t is None
+                           or req.arrival_t is None
+                           else req.first_token_t - req.arrival_t),
+                "n_retries": req.n_retries,
+            })
 
     def _close_terminal_streams(self) -> None:
         """Requests that will never produce tokens (expired in queue,
@@ -386,63 +410,43 @@ class HttpFrontDoor:
         except (ValueError, UnicodeDecodeError):
             await self._json(writer, 400, {"error": "bad json"})
             return
-        prompt = spec.get("prompt") or []
-        req, stream = self.router.submit(
-            prompt, int(spec.get("max_new_tokens", 16)),
-            priority=int(spec.get("priority", 0)),
-            deadline_s=spec.get("deadline_s"))
         writer.write(b"HTTP/1.1 200 OK\r\n"
                      b"Content-Type: application/x-ndjson\r\n"
                      b"Transfer-Encoding: chunked\r\n"
                      b"Connection: close\r\n\r\n")
-        await writer.drain()
+        sink = _Delivery(self, writer)
+        req = self.router.submit(
+            spec.get("prompt") or [], int(spec.get("max_new_tokens", 16)),
+            sink, priority=int(spec.get("priority", 0)),
+            deadline_s=spec.get("deadline_s"))
         # the request body is fully read, so any further read resolving
         # means the client went away (EOF / reset) -> cancel mid-flight
         watchdog = asyncio.ensure_future(reader.read(1))
-        tracer = self.obs.tracer
         try:
-            while True:
-                getter = asyncio.ensure_future(stream.get())
-                await asyncio.wait({getter, watchdog},
+            while not sink.finished:
+                await asyncio.wait({sink.cue, watchdog},
                                    return_when=asyncio.FIRST_COMPLETED)
-                if getter.done():
-                    item = getter.result()
-                    if item is None:
-                        break
-                    self._chunk(writer, item)
-                    await writer.drain()
-                    if tracer is not None:
-                        # delivery, on the router's clock: the token left
-                        # the server now; it was committed at item["t"]
-                        self.obs.trace("sent", self.router.clock,
-                                       rid=req.rid, committed=item["t"])
-                else:                      # client disconnected
-                    getter.cancel()
-                    self.obs.m_http_disconnects.inc()
-                    self.router.cancel(req)
+                if not sink.cue.done():    # client disconnected
+                    self._disconnected(writer, req)
                     return
-            self._chunk(writer, {
-                "event": "end", "rid": req.rid,
-                "n_tokens": len(req.generated), "done": req.done,
-                "ttft_s": (None if req.first_token_t is None
-                           or req.arrival_t is None
-                           else req.first_token_t - req.arrival_t),
-                "n_retries": req.n_retries,
-            })
-            writer.write(b"0\r\n\r\n")
-            await writer.drain()
+                if sink.backlog:
+                    # delivery outran the socket: wait until it takes
+                    # more, then write what waited
+                    self.obs.m_http_backpressure_waits.inc()
+                    await writer.drain()
+                    sink.flush()
         except (ConnectionResetError, BrokenPipeError):
-            self.obs.m_http_disconnects.inc()
-            self.router.cancel(req)
+            self._disconnected(writer, req)
         finally:
             watchdog.cancel()
 
-    # -- wire helpers ----------------------------------------------------
+    def _disconnected(self, writer: asyncio.StreamWriter,
+                      req: Request) -> None:
+        writer.close()        # so the cancelled stream writes nothing more
+        self.obs.m_http_disconnects.inc()
+        self.router.cancel(req)
 
-    @staticmethod
-    def _chunk(writer: asyncio.StreamWriter, obj: Any) -> None:
-        data = (json.dumps(obj) + "\n").encode()
-        writer.write(f"{len(data):x}\r\n".encode() + data + b"\r\n")
+    # -- wire helpers ----------------------------------------------------
 
     @staticmethod
     async def _json(writer: asyncio.StreamWriter, status: int,
@@ -467,3 +471,59 @@ class HttpFrontDoor:
             f"Content-Length: {len(body)}\r\n"
             f"Connection: close\r\n\r\n".encode() + body)
         await writer.drain()
+
+
+class _Delivery:
+    """One HTTP stream's sink (``EventRouter.submit``): writes each item
+    as an NDJSON chunk straight into the client's transport, inside the
+    round that committed it, and the end item with the chunked-body
+    terminator. While the transport's buffer is above its high-water
+    mark, items wait in ``backlog`` and ``cue`` wakes the handler to
+    drain the socket; ``cue`` also resolves once the end is written."""
+
+    def __init__(self, door: HttpFrontDoor, writer: asyncio.StreamWriter):
+        self.door = door
+        self.transport = writer.transport
+        self.tracer = door.obs.tracer
+        self.backlog: deque = deque()
+        self.cue: asyncio.Future = asyncio.get_running_loop().create_future()
+        self.finished = False     # the end chunk is written
+
+    def __call__(self, req: Request, item: Dict[str, Any]) -> None:
+        if self.backlog or self._above_mark():
+            self.backlog.append((req, item))
+            self._rouse()
+        else:
+            self._write(req, item)
+
+    def flush(self) -> None:
+        """After a drain: write what waited, until the transport's
+        buffer is above its mark again."""
+        self.cue = asyncio.get_running_loop().create_future()
+        while self.backlog and not self._above_mark():
+            self._write(*self.backlog.popleft())
+        if self.backlog:
+            self._rouse()
+
+    def _above_mark(self) -> bool:
+        t = self.transport
+        return t.get_write_buffer_size() > t.get_write_buffer_limits()[1]
+
+    def _write(self, req: Request, item: Dict[str, Any]) -> None:
+        end = "event" in item
+        if not self.transport.is_closing():
+            data = (json.dumps(item) + "\n").encode()
+            self.transport.write(f"{len(data):x}\r\n".encode() + data
+                                 + (b"\r\n0\r\n\r\n" if end else b"\r\n"))
+            if self.tracer is not None and not end:
+                # delivery, on the router's clock: the token is handed
+                # to the socket now; it was committed at item["t"]
+                self.door.obs.trace("sent", self.door.router.clock,
+                                    rid=req.rid, committed=item["t"])
+        if end:
+            self.finished = True
+            self._rouse()
+
+    def _rouse(self) -> None:
+        if not self.cue.done():
+            self.cue.set_result(None)

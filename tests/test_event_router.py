@@ -19,7 +19,9 @@ The tentpole claims, pinned:
     chunks to 8 concurrent clients with REAL (measured) TTFT/TPOT; a
     mid-flight disconnect cancels the request and frees its cache row
     without killing the round; requests the cache can never hold end
-    their streams cleanly instead of hanging the client.
+    their streams cleanly instead of hanging the client. Each token is
+    handed to its socket in the round that commits it, and a client
+    that stops reading is backed up without stalling the others.
 
 Async/event-loop tests run under a per-test ``signal.alarm`` guard so
 a stuck loop fails loudly instead of hanging the suite.
@@ -27,6 +29,7 @@ a stuck loop fails loudly instead of hanging the suite.
 import asyncio
 import json
 import signal
+import socket
 
 import jax
 import numpy as np
@@ -351,20 +354,25 @@ async def _generate(port, i, n_new=5, disconnect_after=None):
     while (await reader.readline()) not in (b"\r\n", b"\n"):
         pass
     chunks = []
-    while True:
-        size = int((await reader.readline()).strip() or b"0", 16)
-        if size == 0:
-            break
-        chunks.append(json.loads(await reader.readexactly(size)))
-        await reader.readexactly(2)          # chunk trailer CRLF
+    while (chunk := await _read_chunk(reader)) is not None:
+        chunks.append(chunk)
         if disconnect_after is not None and len(chunks) >= disconnect_after:
-            writer.close()
-            return chunks
+            break
     writer.close()
     return chunks
 
 
-async def _get(port, path):
+async def _read_chunk(reader):
+    """One NDJSON chunk of a chunked body, or None at the terminator."""
+    size = int((await reader.readline()).strip() or b"0", 16)
+    if size == 0:
+        return None
+    chunk = json.loads(await reader.readexactly(size))
+    await reader.readexactly(2)              # chunk trailer CRLF
+    return chunk
+
+
+async def _get(port, path, parse=json.loads):
     reader, writer = await asyncio.open_connection("127.0.0.1", port)
     writer.write(f"GET {path} HTTP/1.1\r\nHost: t\r\n\r\n".encode())
     await writer.drain()
@@ -375,7 +383,7 @@ async def _get(port, path):
         headers[k.strip().lower()] = v.strip()
     body = await reader.readexactly(int(headers["content-length"]))
     writer.close()
-    return status, json.loads(body)
+    return status, parse(body)
 
 
 def _door(engine, params, obs=None, **pool_kw):
@@ -536,3 +544,116 @@ def test_http_untraced_records_nothing_and_streams_same_tokens(stack,
         return [[c["token"] for c in chunks[:-1]] for chunks in streams]
     assert tokens(plain) == tokens(traced)
 
+
+def test_http_tokens_handed_to_socket_in_the_round_that_commits_them(stack):
+    """Delivery on commit: every token's ``sent`` event comes before the
+    next ``round`` event of the replica whose round committed it, so no
+    token waits a round in the server; every stream arrives whole and in
+    order."""
+    engine, params, _ = stack
+    N_CLIENTS, N_NEW = 4, 8
+    obs = Observability(tracer=TraceRecorder())
+
+    async def main():
+        router, door = _door(engine, params, obs=obs, max_len=24)
+        await door.start()
+        streams = await asyncio.gather(
+            *(_generate(door.port, i, n_new=N_NEW)
+              for i in range(N_CLIENTS)))
+        await door.close()
+        return streams
+
+    streams = asyncio.run(main())
+    events = obs.tracer.events
+    rounds = [(i, e) for i, e in enumerate(events) if e["event"] == "round"]
+    sent = [(i, e) for i, e in enumerate(events) if e["event"] == "sent"]
+    assert len(sent) == N_CLIENTS * N_NEW
+    for i, s in sent:
+        # the committing round: the latest of the stream's rounds that
+        # began at or before the commit
+        j, commit = max(((j, r) for j, r in rounds
+                         if s["rid"] in r["rids"]
+                         and r["t"] <= s["committed"]),
+                        key=lambda jr: jr[1]["t"])
+        nxt = [k for k, r in rounds
+               if k > j and r["replica"] == commit["replica"]]
+        assert not nxt or i < nxt[0], (s, commit)
+    for chunks in streams:
+        toks, end = chunks[:-1], chunks[-1]
+        assert len(toks) == N_NEW and end["event"] == "end"
+        assert end["n_tokens"] == N_NEW and end["done"]
+        assert [c["prefill"] for c in toks] == [True] + [False] * (N_NEW - 1)
+        assert [c["done"] for c in toks] == [False] * (N_NEW - 1) + [True]
+        mine = [s for _, s in sent if s["rid"] == end["rid"]]
+        assert [s["committed"] for s in mine] == [c["t"] for c in toks]
+
+
+class _NarrowDoor(HttpFrontDoor):
+    """A front door whose sockets push back early: a small kernel send
+    buffer and a small transport high-water mark, so a client that stops
+    reading fills them within a few hundred tokens."""
+
+    async def _handle(self, reader, writer):
+        writer.get_extra_info("socket").setsockopt(
+            socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
+        writer.transport.set_write_buffer_limits(high=1024)
+        await super()._handle(reader, writer)
+
+
+def test_http_paused_client_backpressure_buffers_without_stalling_others(
+        stack):
+    """A client that stops reading mid-stream (small receive buffer,
+    reads paused) makes its handler wait on the socket's drain while the
+    rounds go on: another client streams to completion meanwhile, the
+    paused client then receives every token in order, and the waits are
+    counted in ``repro_http_backpressure_waits_total`` on ``/metrics``."""
+    engine, params, _ = stack
+    N_SLOW, N_FAST = 300, 6
+
+    async def main():
+        router = EventRouter(
+            _pool(engine, params, lat=WALL_LAT, n_slots=4,
+                  max_len=PROMPT + N_SLOW + 8),
+            QueueDepthPolicy(max_replicas=1), clock=WallClock(),
+            traffic_name="http")
+        door = _NarrowDoor(router, port=0)
+        await door.start()
+        loop = asyncio.get_running_loop()
+        sock = socket.socket()
+        sock.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 1024)
+        sock.setblocking(False)
+        await loop.sock_connect(sock, ("127.0.0.1", door.port))
+        reader, writer = await asyncio.open_connection(sock=sock, limit=256)
+        body = json.dumps({"prompt": [3] * PROMPT, "max_new_tokens": N_SLOW})
+        writer.write((f"POST /v1/generate HTTP/1.1\r\nHost: t\r\n"
+                      f"Content-Length: {len(body)}\r\n\r\n{body}").encode())
+        await writer.drain()
+        assert b"200" in await reader.readline()
+        while (await reader.readline()) not in (b"\r\n", b"\n"):
+            pass
+        first = await _read_chunk(reader)          # mid-stream: now pause
+        fast = await asyncio.wait_for(_generate(door.port, 1,
+                                                n_new=N_FAST), 60)
+        while len(router.completed) < 2:          # the slow stream ends
+            await asyncio.sleep(0.01)
+        _, metrics = await _get(door.port, "/metrics", bytes.decode)
+        rest = []
+        while (chunk := await _read_chunk(reader)) is not None:
+            rest.append(chunk)
+        writer.close()
+        await door.close()
+        return router, fast, [first] + rest, metrics
+
+    router, fast, slow, metrics = asyncio.run(main())
+    assert len(fast) == N_FAST + 1 and fast[-1]["done"]
+    toks, end = slow[:-1], slow[-1]
+    assert len(toks) == N_SLOW and end["event"] == "end" and end["done"]
+    assert end["n_tokens"] == N_SLOW
+    assert [c["done"] for c in toks] == [False] * (N_SLOW - 1) + [True]
+    assert all(c0["t"] <= c1["t"] for c0, c1 in zip(toks, toks[1:]))
+    served = next(q for q in router.completed if q.rid == end["rid"])
+    assert [c["token"] for c in toks] == [int(t) for t in served.generated]
+    waits = [ln for ln in metrics.splitlines()
+             if ln.startswith("repro_http_backpressure_waits_total ")]
+    assert len(waits) == 1 and float(waits[0].split()[1]) > 0
+    assert router.report().n_cancelled == 0
