@@ -118,6 +118,14 @@ class Observability:
             "Billed busy seconds attributed to DAG stages.",
             labelnames=("stage",))
 
+        # -- engine placement (Engine.attach_obs) --
+        self.m_engine_mesh_devices = r.gauge(
+            "repro_engine_mesh_devices",
+            "Devices in the serving engine's mesh (1 without a mesh).")
+        self.m_engine_param_bytes = r.gauge(
+            "repro_engine_param_bytes_per_device",
+            "Parameter bytes each device of the serving engine holds.")
+
         # -- HTTP front door --
         self.m_http_inflight = r.gauge(
             "repro_http_inflight", "HTTP requests currently being served.")

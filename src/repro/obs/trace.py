@@ -51,10 +51,10 @@ SPAN_EVENTS = ("queued", "admitted", "prefill", "first_token", "finish",
 TERMINAL_EVENTS = ("finish", "cancel", "expire", "reject")
 # Per-request delivery to the client; may follow the terminal event.
 DELIVERY_EVENTS = ("sent",)
-# Non-request events: the wall clock's origin, per-round attribution,
-# pool/scaling transitions.
-SYSTEM_EVENTS = ("clock", "round", "replica_start", "replica_ready",
-                 "replica_crash", "replica_retire", "scale")
+# Non-request events: the wall clock's origin, the engine's placement
+# under a mesh, per-round attribution, pool/scaling transitions.
+SYSTEM_EVENTS = ("clock", "layout", "round", "replica_start",
+                 "replica_ready", "replica_crash", "replica_retire", "scale")
 SPAN_PREFIX = "repro:"
 
 

@@ -277,9 +277,10 @@ class RouterCore:
 
     def attach_obs(self, obs: Any) -> Any:
         """Attach an ``Observability`` (registry + optional tracer) to
-        this core and its pool. The HTTP front door calls this when the
-        router was built without one, so ``GET /metrics`` always has a
-        registry behind it."""
+        this core, its pool and the pool's engine (which reports its
+        placement, ``Engine.attach_obs``). The HTTP front door calls
+        this when the router was built without one, so ``GET /metrics``
+        always has a registry behind it."""
         self.obs = obs
         if getattr(self.pool, "obs", None) is None:
             self.pool.obs = obs
@@ -287,6 +288,7 @@ class RouterCore:
             # the wall clock's zero on the host's monotonic scale, so
             # client-side stamps can be read against the trace's times
             obs.trace("clock", 0.0, monotonic=self._clock.origin)
+        self.pool.engine.attach_obs(obs, self._clock.now)
         return obs
 
     # -- the clock -------------------------------------------------------

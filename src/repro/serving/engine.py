@@ -26,9 +26,10 @@ assert executable reuse. See serving/README.md for the full contract.
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from contextlib import nullcontext
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,7 +40,7 @@ from repro.dist import context as dctx
 from repro.dist import sharding as shd
 from repro.kernels.decode_attention.fused_sampling import fused_sample
 from repro.kernels.decode_attention.quant import KV_DTYPES
-from repro.models.common import RunConfig
+from repro.models.common import RunConfig, is_axspec
 from repro.models.model_zoo import Model
 from repro.obs.trace import span
 from repro.serving.sampler import sample
@@ -80,6 +81,8 @@ class Engine:
       seq_shard: shard the KV-cache SEQUENCE dim over the "model" axis
         (the layout ``dist.collectives.seq_sharded_*`` consumes) instead
         of the default kv-heads layout.
+      obs, now: set by :meth:`attach_obs`; ``for_mesh`` carries them to
+        each slice engine.
     """
 
     model: Model
@@ -88,6 +91,10 @@ class Engine:
     mesh: Optional[jax.sharding.Mesh] = None
     strategy: Optional[str] = None
     seq_shard: bool = False
+    obs: Optional[Any] = dataclasses.field(default=None, repr=False,
+                                           compare=False)
+    now: Optional[Callable[[], float]] = dataclasses.field(
+        default=None, repr=False, compare=False)
 
     def __post_init__(self):
         if self.run.kv_dtype not in KV_DTYPES:
@@ -142,6 +149,50 @@ class Engine:
         and because slices share axis names and shapes, every slice
         engine compiles the same executable buckets — once per slice."""
         return dataclasses.replace(self, mesh=mesh)
+
+    # ------------------------------------------------------------------
+    # Observability: what this engine serves, readable from the run
+    # ------------------------------------------------------------------
+
+    def attach_obs(self, obs, now: Callable[[], float]) -> None:
+        """Report this engine's placement on ``obs`` (a
+        ``repro.obs.Observability``), stamping trace events with
+        ``now()``: the gauges ``repro_engine_mesh_devices`` and
+        ``repro_engine_param_bytes_per_device`` at once, and, with a
+        tracer, one ``layout`` event each time :meth:`new_cache` builds a
+        serving cache under a mesh."""
+        self.obs, self.now = obs, now
+        self._report_layout()
+
+    def param_bytes_per_device(self) -> int:
+        """Parameter bytes one device holds in the planned layout (all of
+        them without a mesh)."""
+        specs = jax.tree.leaves(self.model.param_specs, is_leaf=is_axspec)
+        if self.mesh is None:
+            shapes = [s.shape for s in specs]
+        else:
+            shapes = [sh.shard_shape(s.shape) for s, sh in
+                      zip(specs, jax.tree.leaves(self.params_sharding))]
+        return sum(math.prod(shape) * jnp.dtype(s.dtype).itemsize
+                   for s, shape in zip(specs, shapes))
+
+    def _report_layout(self, cache_specs=None) -> None:
+        obs = self.obs
+        if obs is None:
+            return
+        n_dev = self.mesh.size if self.mesh is not None else 1
+        per_dev = self.param_bytes_per_device()
+        obs.m_engine_mesh_devices.set(n_dev)
+        obs.m_engine_param_bytes.set(per_dev)
+        if cache_specs is None or self.mesh is None:
+            return
+        kv = {tuple(sh.spec) for sh in jax.tree.leaves(
+            self.cache_sharding(cache_specs)) if len(sh.spec) >= 4}
+        obs.trace("layout", self.now(),
+                  mesh=dict(self.mesh.shape), strategy=self.strategy,
+                  seq_shard=self.seq_shard, attn_impl=self.run.attn_impl,
+                  param_bytes_per_device=per_dev,
+                  kv_specs=[list(spec) for spec in sorted(kv, key=str)])
 
     def shard_params(self, params):
         """Place ``params`` on the device(s): the planner layout under a
@@ -334,6 +385,7 @@ class Engine:
         if self.mesh is None:
             return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
                                 specs)
+        self._report_layout(specs)
         with self._ctx():
             fn = self._get_exec(
                 "new_cache", _shape_key(specs),

@@ -403,3 +403,23 @@ def test_live_stats_is_o1_and_never_calls_report(stack):
                                    for r in router.completed)
     assert ls["cost_usd"] == pytest.approx(rep.cost_usd, abs=1e-8)
     assert ls["ttft_p50_s"] > 0          # registry bucket-boundary p50
+
+
+def test_router_attaches_its_observability_to_the_engine(stack):
+    """The router hands its registry to the pool's engine, which sets the
+    placement gauges at once; without a mesh they read one device holding
+    every parameter, and the engine writes no ``layout`` event."""
+    model, cfg, _, params = stack
+    engine = Engine(model, RunConfig(cache_pad=8))
+    obs = Observability(tracer=TraceRecorder())
+    router, _ = _run(EventRouter, "run_events", engine, params, cfg,
+                     paged=False, obs=obs)
+    assert engine.obs is obs
+    text = obs.registry.render()
+    assert lint_prometheus(text) == []
+    assert "repro_engine_mesh_devices 1" in text.splitlines()
+    total = sum(l.nbytes for l in jax.tree.leaves(params))
+    assert f"repro_engine_param_bytes_per_device {total}" in \
+        text.splitlines()
+    assert router.completed
+    assert not [e for e in obs.tracer.events if e["event"] == "layout"]
