@@ -7,9 +7,10 @@ block (running max, exp-sum, weighted values) and the shards combine with
 one pmax + two psums — the cache never materializes unsharded. The write
 variant also writes each row's new K/V into whichever shard owns that
 row's global position ``lengths[b]``, shard-locally, so SPMD can't decide
-to all-gather the cache around the update. ``lengths`` is scalar or (B,)
-— per-row lengths are what let one shared batched cache serve ragged
-continuous-batching rows in a single dispatch.
+to all-gather the cache around the update; given the decode step's layer
+stacks and a layer index it writes into the stack in place. ``lengths``
+is scalar or (B,) — per-row lengths are what let one shared batched
+cache serve ragged continuous-batching rows in a single dispatch.
 
 The per-shard block is the ``kernels/decode_attention`` Pallas kernel
 (``decode_attention_partials``) on TPU; off-TPU it runs the identical
@@ -89,24 +90,6 @@ def _combine_local(q, num, den):
     return o.reshape(b, 1, h, hd).astype(q.dtype)
 
 
-def _write_at(cache, new, indices):
-    """Write ``new`` (B,1,KV,hd) at each row's local position
-    ``indices[b]`` iff 0 <= indices[b] < Sl (rows whose position lives on
-    another shard skip their write). ``indices`` is scalar or (B,)."""
-    sl = cache.shape[1]
-    indices = jnp.broadcast_to(jnp.asarray(indices, jnp.int32),
-                               (cache.shape[0],))
-
-    def one_row(c, n, i):
-        in_range = (i >= 0) & (i < sl)
-        idx = jnp.clip(i, 0, sl - 1)
-        updated = jax.lax.dynamic_update_slice_in_dim(
-            c, n.astype(c.dtype), idx, axis=0)
-        return jnp.where(in_range, updated, c)
-
-    return jax.vmap(one_row)(cache, new, indices)
-
-
 def _shard_plan(mesh, batch: int, seq: int):
     """(batch_spec_entry, manual_axes) for the decode shard_maps, or None
     when the sequence can't shard over "model".
@@ -169,34 +152,49 @@ def seq_sharded_decode(q, k_cache, v_cache, lengths, *,
 
 def seq_sharded_write_decode(q, k_new, v_new, k_cache, v_cache, lengths, *,
                              window: Optional[int] = None,
-                             cap: Optional[float] = None):
+                             cap: Optional[float] = None, layer=None):
     """Fused cache-write + decode attention over a sequence-sharded cache.
 
     Writes k_new/v_new (B,1,KV,hd) at each row's global position
-    ``lengths[b]`` — inside the shard that owns it — then attends q over
-    the updated cache (row b sees positions <= lengths[b]). ``lengths``
-    is scalar or (B,). Returns (out (B,1,H,hd), new_k_cache,
-    new_v_cache); the caches keep their (B, S/"model", KV, hd) sharding.
+    ``lengths[b]`` — inside the shard that owns it, as one scatter that
+    drops the rows whose position lives on another shard — then attends
+    q over the updated cache (row b sees positions <= lengths[b]).
+    ``lengths`` is scalar or (B,). The caches are one layer's
+    (B,S,KV,hd), or with ``layer`` the decode step's layer stacks
+    (G,B,S,KV,hd), written at (layer, b, ·) in place and read at layer
+    ``layer``. Returns (out (B,1,H,hd), new_k_cache, new_v_cache); the
+    caches keep their sequence-over-"model" sharding.
     """
+    if layer is None:  # one layer's cache is a stack of one
+        o, kc, vc = seq_sharded_write_decode(
+            q, k_new, v_new, k_cache[None], v_cache[None], lengths,
+            window=window, cap=cap, layer=0)
+        return o, kc[0], vc[0]
+    from repro.models.attention import layer_view, write_kv_rows
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32),
                                (q.shape[0],))
-    plan = _shard_plan(ctx.get_mesh(), q.shape[0], k_cache.shape[1])
+    layer = jnp.asarray(layer, jnp.int32)
+    plan = _shard_plan(ctx.get_mesh(), q.shape[0], k_cache.shape[2])
     if plan is None:
-        kc = _write_at(k_cache, k_new, lengths)
-        vc = _write_at(v_cache, v_new, lengths)
-        num, den, _ = _partial_decode(q, kc, vc, lengths, 0, window, cap)
+        kc = write_kv_rows(k_cache, k_new, lengths, layer)
+        vc = write_kv_rows(v_cache, v_new, lengths, layer)
+        num, den, _ = _partial_decode(q, layer_view(kc, layer),
+                                      layer_view(vc, layer), lengths, 0,
+                                      window, cap)
         return _combine_local(q, num, den), kc, vc
     bspec, manual = plan
     mesh = ctx.get_mesh()
     from jax.sharding import PartitionSpec as P
     rep = P(bspec, None, None, None)
-    shc = P(bspec, "model", None, None)
+    shc = P(None, bspec, "model", None, None)
 
-    def body(q, kn, vn, kc, vc, lengths):
-        off = jax.lax.axis_index("model") * kc.shape[1]
-        kc = _write_at(kc, kn, lengths - off)
-        vc = _write_at(vc, vn, lengths - off)
-        num, den, m = _partial_decode(q, kc, vc, lengths, off, window, cap)
+    def body(q, kn, vn, kc, vc, lengths, layer):
+        off = jax.lax.axis_index("model") * kc.shape[2]
+        kc = write_kv_rows(kc, kn, lengths - off, layer)
+        vc = write_kv_rows(vc, vn, lengths - off, layer)
+        num, den, m = _partial_decode(q, layer_view(kc, layer),
+                                      layer_view(vc, layer), lengths, off,
+                                      window, cap)
         m_g = jax.lax.pmax(m, "model")
         scale = jnp.exp(m - m_g)
         num = jax.lax.psum(num * scale[..., None], "model")
@@ -205,10 +203,10 @@ def seq_sharded_write_decode(q, k_new, v_new, k_cache, v_cache, lengths, *,
 
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(rep, rep, rep, shc, shc, P(bspec)),
+        in_specs=(rep, rep, rep, shc, shc, P(bspec), P()),
         out_specs=(rep, shc, shc),
         axis_names=manual, check_vma=False)(
-            q, k_new, v_new, k_cache, v_cache, lengths)
+            q, k_new, v_new, k_cache, v_cache, lengths, layer)
 
 
 # ---------------------------------------------------------------------------

@@ -266,22 +266,38 @@ def attn_forward(cfg: ModelConfig, p, x, *, mixer: str, positions,
     return (y, (k, v)) if return_kv else y
 
 
-def write_kv_rows(cache, new, lengths):
-    """Write ``new`` (B,1,KV,hd) into ``cache`` (B,Smax,KV,hd) at each
-    row's own position ``lengths[b]`` (per-row dynamic_update_slice —
-    lowers to one scatter, so decode HBM traffic stays one token/row)."""
-    lengths = row_lengths(lengths, cache.shape[0])
+def write_kv_rows(cache, new, lengths, layer=None):
+    """Write ``new`` (B,1,KV,hd) at each row's own position ``lengths[b]``
+    as one scatter of B token rows, so a step's HBM traffic for the write
+    is one token per row.
 
-    def one_row(c, n, l):
-        return jax.lax.dynamic_update_slice_in_dim(
-            c, n.astype(c.dtype), l, axis=0)
+    ``cache`` is one layer's (B,Smax,KV,hd), or with ``layer`` the decode
+    step's layer stack (G,B,Smax,KV,hd), written at (layer, b,
+    lengths[b]): the stack is updated where it lies (in place in the
+    scan carry) and never copied out and back. A row whose position falls
+    outside [0, Smax) writes nothing.
+    """
+    b, smax = new.shape[0], cache.shape[-3]
+    pos = row_lengths(lengths, b)
+    # a negative index would wrap round: send it past the end, dropped
+    pos = jnp.where((pos >= 0) & (pos < smax), pos, smax)
+    idx = (jnp.arange(b), pos)
+    if layer is not None:
+        idx = (layer,) + idx
+    return cache.at[idx].set(new[:, 0].astype(cache.dtype), mode="drop")
 
-    return jax.vmap(one_row)(cache, new, lengths)
+
+def layer_view(t, layer):
+    """Layer ``layer`` of a (G, ...) cache stack, for reading only. A
+    per-layer cache (``layer`` None) or an absent leaf passes through."""
+    if t is None or layer is None:
+        return t
+    return jax.lax.dynamic_index_in_dim(t, layer, 0, keepdims=False)
 
 
 def attn_decode_layer(cfg: ModelConfig, p, x, k_cache, v_cache, lengths, *,
                       mixer: str, impl: str = "xla",
-                      k_scale=None, v_scale=None):
+                      k_scale=None, v_scale=None, layer=None):
     """Decode sublayer: project, write new kv at each row's ``lengths[b]``,
     attend.
 
@@ -291,15 +307,20 @@ def attn_decode_layer(cfg: ModelConfig, p, x, k_cache, v_cache, lengths, *,
     depths (the ragged batch of ``serving.ContinuousBatcher``). Under
     ``impl="seq_shard"`` each row's write happens inside the shard that
     owns its global position (fused with the attention in one shard_map),
-    so SPMD never gathers the cache around the update; other impls use a
-    per-row dynamic_update_slice.
+    so SPMD never gathers the cache around the update; other impls write
+    with one scatter (:func:`write_kv_rows`).
 
-    When ``k_scale``/``v_scale`` ((B,Smax,KV,1) fp32 scale caches) are
-    given the kv caches are int8: the new token's post-RoPE k/v are
-    quantized per token, both the int8 values and the scales are written
-    at ``lengths[b]``, and the return grows to the 5-tuple
-    (y, k_cache, v_cache, k_scale, v_scale) — callers that never pass
-    scales keep the 3-tuple contract unchanged.
+    With ``layer`` the caches are the decode step's layer stacks
+    (G,B,Smax,KV,hd): the new token lands at (layer, b, lengths[b]) in
+    the stack itself and attention reads layer ``layer`` of the updated
+    stack, so no layer's cache is copied out and written back.
+
+    When ``k_scale``/``v_scale`` ((B,Smax,KV,1) fp32 scale caches, or
+    their layer stacks) are given the kv caches are int8: the new token's
+    post-RoPE k/v are quantized per token, both the int8 values and the
+    scales are written at ``lengths[b]``, and the return grows to the
+    5-tuple (y, k_cache, v_cache, k_scale, v_scale) — callers that never
+    pass scales keep the 3-tuple contract unchanged.
     """
     b = x.shape[0]
     lengths = row_lengths(lengths, b)
@@ -320,19 +341,21 @@ def attn_decode_layer(cfg: ModelConfig, p, x, k_cache, v_cache, lengths, *,
         from repro.dist import collectives
         o, k_cache, v_cache = collectives.seq_sharded_write_decode(
             q, k, v, k_cache, v_cache, lengths, window=window,
-            cap=cfg.attn_softcap)
+            cap=cfg.attn_softcap, layer=layer)
         return out_proj(p, o), k_cache, v_cache
     if k_scale is not None:
         from repro.kernels.decode_attention.quant import quantize_kv
         k, ks_new = quantize_kv(k)   # (B,1,KV,hd) int8, (B,1,KV,1) fp32
         v, vs_new = quantize_kv(v)
-        k_scale = write_kv_rows(k_scale, ks_new, lengths)
-        v_scale = write_kv_rows(v_scale, vs_new, lengths)
-    k_cache = write_kv_rows(k_cache, k, lengths)
-    v_cache = write_kv_rows(v_cache, v, lengths)
-    o = attend_decode(q, k_cache, v_cache, lengths, k_scale=k_scale,
-                      v_scale=v_scale, window=window,
-                      cap=cfg.attn_softcap, impl=impl)
+        k_scale = write_kv_rows(k_scale, ks_new, lengths, layer)
+        v_scale = write_kv_rows(v_scale, vs_new, lengths, layer)
+    k_cache = write_kv_rows(k_cache, k, lengths, layer)
+    v_cache = write_kv_rows(v_cache, v, lengths, layer)
+    o = attend_decode(q, layer_view(k_cache, layer),
+                      layer_view(v_cache, layer), lengths,
+                      k_scale=layer_view(k_scale, layer),
+                      v_scale=layer_view(v_scale, layer),
+                      window=window, cap=cfg.attn_softcap, impl=impl)
     if k_scale is not None:
         return out_proj(p, o), k_cache, v_cache, k_scale, v_scale
     return out_proj(p, o), k_cache, v_cache
@@ -344,14 +367,16 @@ def attn_decode_layer(cfg: ModelConfig, p, x, k_cache, v_cache, lengths, *,
 # ---------------------------------------------------------------------------
 
 
-def write_kv_pages(pool, new, page_table, lengths, page_size: int):
-    """Write ``new`` (B,1,KV,hd) into the shared page pool at each row's
-    own logical position ``lengths[b]``, resolved through its page table.
+def write_kv_pages(pool, new, page_table, lengths, page_size: int, layer):
+    """Write ``new`` (B,1,KV,hd) into layer ``layer`` of the shared page
+    pool at each row's own logical position ``lengths[b]``, resolved
+    through its page table: one scatter into the stack, in place.
 
-    pool: (P, page_size, KV, hd). The serving layer guarantees (via the
-    allocator's copy-on-write barrier) that no two ACTIVE rows resolve
-    their write position to the same physical page; free rows all write
-    into the reserved null page 0, which is never allocated.
+    pool: the layer stack (G, P, page_size, KV, hd). The serving layer
+    guarantees (via the allocator's copy-on-write barrier) that no two
+    ACTIVE rows resolve their write position to the same physical page;
+    free rows all write into the reserved null page 0, which is never
+    allocated.
     """
     b = new.shape[0]
     lengths = row_lengths(lengths, b)
@@ -359,7 +384,7 @@ def write_kv_pages(pool, new, page_table, lengths, page_size: int):
     slot = jnp.clip(lengths // page_size, 0, pmax - 1)
     pages = jnp.take_along_axis(page_table, slot[:, None], axis=1)[:, 0]
     offs = lengths % page_size
-    return pool.at[pages, offs].set(new[:, 0].astype(pool.dtype))
+    return pool.at[layer, pages, offs].set(new[:, 0].astype(pool.dtype))
 
 
 def attend_decode_paged(q, k_pages, v_pages, page_table, lengths, *,
@@ -405,11 +430,12 @@ def attend_decode_paged(q, k_pages, v_pages, page_table, lengths, *,
 
 def attn_decode_layer_paged(cfg: ModelConfig, p, x, k_pages, v_pages,
                             page_table, lengths, *, mixer: str,
-                            page_size: int, impl: str = "xla",
+                            page_size: int, layer, impl: str = "xla",
                             k_scale=None, v_scale=None):
     """Paged counterpart of :func:`attn_decode_layer`: project, write the
-    new kv through each row's page table, attend through the same table.
-    Returns (y, new_k_pages, new_v_pages) — or, when ``k_scale``/
+    new kv through each row's page table into layer ``layer`` of the pool
+    stacks (G, P, ps, KV, hd), attend through the same table over that
+    layer. Returns (y, new_k_pages, new_v_pages) — or, when ``k_scale``/
     ``v_scale`` scale pools are given (int8 pools), the 5-tuple
     (y, k_pages, v_pages, k_scale, v_scale) with the new token's
     post-RoPE k/v quantized and its scales written through the SAME page
@@ -427,13 +453,17 @@ def attn_decode_layer_paged(cfg: ModelConfig, p, x, k_pages, v_pages,
         k, ks_new = quantize_kv(k)
         v, vs_new = quantize_kv(v)
         k_scale = write_kv_pages(k_scale, ks_new, page_table, lengths,
-                                 page_size)
+                                 page_size, layer)
         v_scale = write_kv_pages(v_scale, vs_new, page_table, lengths,
-                                 page_size)
-    k_pages = write_kv_pages(k_pages, k, page_table, lengths, page_size)
-    v_pages = write_kv_pages(v_pages, v, page_table, lengths, page_size)
-    o = attend_decode_paged(q, k_pages, v_pages, page_table, lengths,
-                            k_scale=k_scale, v_scale=v_scale,
+                                 page_size, layer)
+    k_pages = write_kv_pages(k_pages, k, page_table, lengths, page_size,
+                             layer)
+    v_pages = write_kv_pages(v_pages, v, page_table, lengths, page_size,
+                             layer)
+    o = attend_decode_paged(q, layer_view(k_pages, layer),
+                            layer_view(v_pages, layer), page_table, lengths,
+                            k_scale=layer_view(k_scale, layer),
+                            v_scale=layer_view(v_scale, layer),
                             window=window, cap=cfg.attn_softcap, impl=impl)
     if k_scale is not None:
         return out_proj(p, o), k_pages, v_pages, k_scale, v_scale
@@ -442,8 +472,10 @@ def attn_decode_layer_paged(cfg: ModelConfig, p, x, k_pages, v_pages,
 
 def attn_extend_layer_paged(cfg: ModelConfig, p, x, k_pages, v_pages,
                             table_row, start, *, mixer: str,
-                            page_size: int, k_scale=None, v_scale=None):
-    """Chunked prefill-with-history for ONE paged row.
+                            page_size: int, layer, k_scale=None,
+                            v_scale=None):
+    """Chunked prefill-with-history for ONE paged row, into layer
+    ``layer`` of the pool stacks (G, P, ps, KV, hd).
 
     x: (1, L, D) — the chunk occupies logical positions
     ``start .. start+L-1`` of the row whose page table is ``table_row``
@@ -473,18 +505,18 @@ def attn_extend_layer_paged(cfg: ModelConfig, p, x, k_pages, v_pages,
         from repro.kernels.decode_attention.quant import quantize_kv
         k, ks_new = quantize_kv(k)   # (1,L,KV,hd) int8, (1,L,KV,1) fp32
         v, vs_new = quantize_kv(v)
-        k_scale = k_scale.at[pages, offs].set(ks_new[0])
-        v_scale = v_scale.at[pages, offs].set(vs_new[0])
-    k_pages = k_pages.at[pages, offs].set(k[0].astype(k_pages.dtype))
-    v_pages = v_pages.at[pages, offs].set(v[0].astype(v_pages.dtype))
+        k_scale = k_scale.at[layer, pages, offs].set(ks_new[0])
+        v_scale = v_scale.at[layer, pages, offs].set(vs_new[0])
+    k_pages = k_pages.at[layer, pages, offs].set(k[0].astype(k_pages.dtype))
+    v_pages = v_pages.at[layer, pages, offs].set(v[0].astype(v_pages.dtype))
     from repro.kernels.decode_attention.ref import gather_pages
+    kl, vl = layer_view(k_pages, layer), layer_view(v_pages, layer)
     if k_scale is not None:
         from repro.kernels.decode_attention.quant import dequantize_kv
-        kr = gather_pages(dequantize_kv(k_pages, k_scale), table_row[None])
-        vr = gather_pages(dequantize_kv(v_pages, v_scale), table_row[None])
-    else:
-        kr = gather_pages(k_pages, table_row[None])  # (1, Pmax*ps, KV, hd)
-        vr = gather_pages(v_pages, table_row[None])
+        kl = dequantize_kv(kl, layer_view(k_scale, layer))
+        vl = dequantize_kv(vl, layer_view(v_scale, layer))
+    kr = gather_pages(kl, table_row[None])  # (1, Pmax*ps, KV, hd)
+    vr = gather_pages(vl, table_row[None])
     window = cfg.window if mixer == "attn_local" else None
     o = _attend_dense(q, kr.astype(q.dtype), vr.astype(q.dtype),
                       mask_kind="causal", window=window,

@@ -429,6 +429,10 @@ def prefill(cfg: ModelConfig, run: RunConfig, params, *, tokens=None,
 # Decode
 # ---------------------------------------------------------------------------
 
+# an attention layer's cache leaves, in the order its decode returns them
+# (the scales only for an int8 cache)
+_KV_KEYS = ("k", "v", "k_scale", "v_scale")
+
 
 def decode_step(cfg: ModelConfig, run: RunConfig, params, cache: Cache,
                 token=None, embedding=None):
@@ -440,11 +444,14 @@ def decode_step(cfg: ModelConfig, run: RunConfig, params, cache: Cache,
     slots at different depths (a freed row just decodes inertly against
     its masked cache — the serving layer discards its token).
 
-    The cache lives in the scan CARRY (not xs/ys): while-loop carries
-    alias in place, so each step's HBM traffic is one token's write +
-    the attention read — stacking the cache through ys instead rewrites
-    a full layer slice per step (measured 8 GB/chip/step on command-r
-    decode_32k, §Perf iteration 9).
+    The cache lives in the scan CARRY (not xs/ys) as the per-pattern
+    layer stacks (G, ...). Each attention layer writes the new token's K
+    and V straight into its stack at (g, b, lengths[b]) — one scatter of
+    B token rows, which XLA applies to the donated carry in place — and
+    attends over layer g read from the updated stack, so no layer's cache
+    is copied out and written back: a step's cache traffic is one token's
+    write per row plus the attention read. SSM state (O(1) per row) is
+    still sliced out of its stack and written back whole.
     """
     paged = isinstance(cache, PagedCache)
     lengths = cache.lengths
@@ -453,37 +460,29 @@ def decode_step(cfg: ModelConfig, run: RunConfig, params, cache: Cache,
 
     def group(carry, gp):
         x, layers, g = carry
-        lc = jax.tree.map(
-            lambda t: jax.lax.dynamic_index_in_dim(t, g, 0, keepdims=False),
-            layers)
-        new_caches = []
-        for spec, p, c in zip(cfg.pattern, gp, lc):
+        new_layers = []
+        for spec, p, c in zip(cfg.pattern, gp, layers):
             h = apply_norm(cfg, p["norm1"], x)
             if spec.mixer.startswith("attn"):
-                quant = "k_scale" in c  # int8 cache layer carries scales
+                kw = dict(mixer=spec.mixer, impl=run.attn_impl, layer=g,
+                          k_scale=c.get("k_scale"), v_scale=c.get("v_scale"))
                 if paged:
                     out = attn_lib.attn_decode_layer_paged(
                         cfg, p["attn"], h, c["k"], c["v"], cache.page_table,
-                        lengths, mixer=spec.mixer,
-                        page_size=cache.page_size, impl=run.attn_impl,
-                        k_scale=c["k_scale"] if quant else None,
-                        v_scale=c["v_scale"] if quant else None)
+                        lengths, page_size=cache.page_size, **kw)
                 else:
                     out = attn_lib.attn_decode_layer(
-                        cfg, p["attn"], h, c["k"], c["v"], lengths,
-                        mixer=spec.mixer, impl=run.attn_impl,
-                        k_scale=c["k_scale"] if quant else None,
-                        v_scale=c["v_scale"] if quant else None)
-                if quant:
-                    h, nk, nv, nks, nvs = out
-                    new_caches.append({"k": nk, "v": nv,
-                                       "k_scale": nks, "v_scale": nvs})
-                else:
-                    h, nk, nv = out
-                    new_caches.append({"k": nk, "v": nv})
+                        cfg, p["attn"], h, c["k"], c["v"], lengths, **kw)
+                h, *kv = out  # k, v, then the scales of an int8 cache
+                new_layers.append(dict(zip(_KV_KEYS, kv)))
             else:
-                h, nc = ssm_lib.ssm_decode(cfg, cfg.ssm, p["ssm"], h, c)
-                new_caches.append(nc)
+                sc = jax.tree.map(
+                    lambda t: jax.lax.dynamic_index_in_dim(
+                        t, g, 0, keepdims=False), c)
+                h, nc = ssm_lib.ssm_decode(cfg, cfg.ssm, p["ssm"], h, sc)
+                new_layers.append(jax.tree.map(
+                    lambda full, new: jax.lax.dynamic_update_index_in_dim(
+                        full, new.astype(full.dtype), g, 0), c, nc))
             if cfg.sandwich_norms:
                 h = apply_norm(cfg, p["post_norm1"], h)
             x = x + h
@@ -497,11 +496,7 @@ def decode_step(cfg: ModelConfig, run: RunConfig, params, cache: Cache,
                 if cfg.sandwich_norms:
                     h = apply_norm(cfg, p["post_norm2"], h)
                 x = x + h
-        new_layers = jax.tree.map(
-            lambda full, new: jax.lax.dynamic_update_index_in_dim(
-                full, new.astype(full.dtype), g, 0),
-            layers, tuple(new_caches))
-        return (x, new_layers, g + 1), None
+        return (x, tuple(new_layers), g + 1), None
 
     (x, new_layers, _), _ = jax.lax.scan(
         group, (x, cache.layers, jnp.zeros((), jnp.int32)),
@@ -545,26 +540,16 @@ def extend_paged(cfg: ModelConfig, run: RunConfig, params, cache: PagedCache,
 
     def group(carry, gp):
         x, layers, g = carry
-        lc = jax.tree.map(
-            lambda t: jax.lax.dynamic_index_in_dim(t, g, 0, keepdims=False),
-            layers)
-        new_caches = []
-        for spec, p, c in zip(cfg.pattern, gp, lc):
+        new_layers = []
+        for spec, p, c in zip(cfg.pattern, gp, layers):
             h = apply_norm(cfg, p["norm1"], x)
             # paged_cache_specs guarantees an attention-only pattern
-            quant = "k_scale" in c
             out = attn_lib.attn_extend_layer_paged(
                 cfg, p["attn"], h, c["k"], c["v"], table_row, start,
-                mixer=spec.mixer, page_size=cache.page_size,
-                k_scale=c["k_scale"] if quant else None,
-                v_scale=c["v_scale"] if quant else None)
-            if quant:
-                h, nk, nv, nks, nvs = out
-                new_caches.append({"k": nk, "v": nv,
-                                   "k_scale": nks, "v_scale": nvs})
-            else:
-                h, nk, nv = out
-                new_caches.append({"k": nk, "v": nv})
+                mixer=spec.mixer, page_size=cache.page_size, layer=g,
+                k_scale=c.get("k_scale"), v_scale=c.get("v_scale"))
+            h, *kv = out  # k, v, then the scales of an int8 cache
+            new_layers.append(dict(zip(_KV_KEYS, kv)))
             if cfg.sandwich_norms:
                 h = apply_norm(cfg, p["post_norm1"], h)
             x = x + h
@@ -578,11 +563,7 @@ def extend_paged(cfg: ModelConfig, run: RunConfig, params, cache: PagedCache,
                 if cfg.sandwich_norms:
                     h = apply_norm(cfg, p["post_norm2"], h)
                 x = x + h
-        new_layers = jax.tree.map(
-            lambda full, new: jax.lax.dynamic_update_index_in_dim(
-                full, new.astype(full.dtype), g, 0),
-            layers, tuple(new_caches))
-        return (x, new_layers, g + 1), None
+        return (x, tuple(new_layers), g + 1), None
 
     (x, new_layers, _), _ = jax.lax.scan(
         group, (x, cache.layers, jnp.zeros((), jnp.int32)),
